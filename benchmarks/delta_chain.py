@@ -32,6 +32,7 @@ from typing import Dict, Iterable, List, Sequence
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.store import VersionStore
 
 from .common import Row
@@ -171,4 +172,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

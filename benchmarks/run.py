@@ -10,6 +10,8 @@ import argparse
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -65,4 +67,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

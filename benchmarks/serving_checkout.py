@@ -38,6 +38,7 @@ from typing import Dict, Iterable, List, Optional
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.store import VersionStore
 
 from .common import Row
@@ -285,4 +286,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

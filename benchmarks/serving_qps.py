@@ -53,6 +53,7 @@ from typing import Dict, Iterable, List, Optional
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.obs import Tracer, chrome_trace, set_tracer, validate_chrome_trace
 from repro.store.repository import Repository
 
@@ -378,4 +379,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -54,6 +54,7 @@ import time
 from pathlib import Path
 from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import OptimizeSpec, WorkloadSpec, generate_flat, optimize
 
 from .common import Row
@@ -295,4 +296,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

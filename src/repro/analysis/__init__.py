@@ -6,11 +6,10 @@ framework:
 * :func:`~repro.analysis.kernel_audit.run_audit` — the **kernel/dispatch
   auditor**: traces every registered Pallas kernel and jitted solver entry
   point to a jaxpr (abstractly, via ``jax.make_jaxpr`` — no accelerator and
-  no execution, so it runs identically with or without
-  ``REPRO_PALLAS_INTERPRET``; that knob only affects runtime interpretation,
-  while the ``pallas_call`` equations the auditor inspects appear in the
-  trace either way) and lints jaxprs + module ASTs for TPU-readiness and
-  dispatch-efficiency hazards.
+  no execution; the ``pallas_call`` equations the auditor inspects appear in
+  the trace whether a kernel would be interpreted or compiled at runtime)
+  and lints jaxprs + module ASTs for TPU-readiness and dispatch-efficiency
+  hazards.
 * :func:`~repro.analysis.fsck.fsck_store` — the **storage-graph fsck**:
   walks a ``VersionStore`` like ``git fsck`` walks an object database
   (also surfaced as ``VersionStore.fsck()`` / ``Repository.fsck()``).

@@ -1,9 +1,9 @@
 """Jaxpr-level kernel/dispatch auditor — the TPU-readiness lint.
 
 Every registered kernel and jitted solver entry point is traced abstractly
-with :func:`jax.make_jaxpr` (no accelerator, no execution — tracing is
-independent of ``REPRO_PALLAS_INTERPRET``; the Pallas calls appear as
-``pallas_call`` equations whether or not they would interpret at runtime)
+with :func:`jax.make_jaxpr` (no accelerator, no execution; the Pallas calls
+appear as ``pallas_call`` equations whether or not they would interpret at
+runtime)
 and the resulting jaxprs are linted against the rule catalog in
 :mod:`repro.analysis`.  Traces run under ``jax_enable_x64`` with each
 target's *production input dtypes*: explicit 64-bit intent (``astype(int64)``,
@@ -34,7 +34,6 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tupl
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from .findings import Finding, Report, Severity
 
@@ -180,7 +179,7 @@ def audit_targets() -> List[AuditTarget]:
 def trace_target(target: AuditTarget):
     """Abstractly trace a target under x64 (see module docstring)."""
     fn, args = target.build()
-    with enable_x64():
+    with jax.enable_x64(True):
         return jax.make_jaxpr(fn)(*args)
 
 
@@ -512,7 +511,7 @@ def bucket_probes() -> List[BucketProbe]:
 def check_bucket_probe(report: Report, p: BucketProbe) -> None:
     report.bump("audit.shape-bucket")
     sigs = {}
-    with enable_x64():
+    with jax.enable_x64(True):
         for n in p.sizes:
             sigs.setdefault(p.trace(n), []).append(n)
     if len(sigs) > 1:

@@ -1,34 +1,28 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
 """Pallas kernel package for the delta/storage hot paths.
 
 Interpret-mode policy
 ---------------------
 Every Pallas kernel in this package (``xor_delta``, ``block_diff``,
 ``sparse_apply``, ``chain_apply``, ``segment_ops``, ``flash_attention``)
-defaults its ``interpret`` flag to the single package-level constant
-:data:`PALLAS_INTERPRET`, read **once at import** from the
-``REPRO_PALLAS_INTERPRET`` environment variable:
-
-* unset / ``1`` / ``true`` → interpret mode (the kernel body runs under the
-  Pallas interpreter — correct everywhere, required on this CPU container);
-* ``0`` / ``false`` / ``no`` / ``off`` → compiled Mosaic lowering for real
-  TPU backends.
-
-A real-TPU run therefore flips one knob (``REPRO_PALLAS_INTERPRET=0``)
-instead of editing per-module ``INTERPRET`` constants.  Call sites may still
-pass ``interpret=`` explicitly (tests exercising both modes do).
+takes ``interpret=None`` by default and resolves it through
+:func:`resolve_interpret` when it is called (traced), never when it is
+imported: the kernel body runs under the Pallas interpreter only where the
+default backend is the CPU, and is compiled through Mosaic on a TPU.  An
+explicit ``interpret=True/False`` overrides the platform (tests exercise
+both, and a compile for a described TPU topology passes ``False``, since the
+process's default backend is still the CPU there).
 """
 
-import os
+from typing import Optional
+
+import jax
 
 
-def interpret_from_env(value: "str | None") -> bool:
-    """Parse the ``REPRO_PALLAS_INTERPRET`` setting (None = unset → True)."""
-    if value is None:
-        return True
-    return value.strip().lower() not in ("0", "false", "no", "off")
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """``interpret`` if given, else whether the default backend is the CPU.
 
-
-PALLAS_INTERPRET = interpret_from_env(os.environ.get("REPRO_PALLAS_INTERPRET"))
+    Initializes JAX's backends on first use, so call it from a kernel
+    wrapper (at trace time), not at import."""
+    if interpret is not None:
+        return bool(interpret)
+    return jax.default_backend() == "cpu"

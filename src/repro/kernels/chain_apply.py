@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import PALLAS_INTERPRET
+from . import resolve_interpret
 
 
 def _fold_dest(idx_flat: jnp.ndarray, num_rows: int) -> jnp.ndarray:
@@ -74,7 +74,8 @@ def _chain_kernel(dest_ref, base_ref, blocks_ref, o_ref):
 
 
 def _chain_call(
-    base: jnp.ndarray, blocks: jnp.ndarray, idx: jnp.ndarray, interpret: bool
+    base: jnp.ndarray, blocks: jnp.ndarray, idx: jnp.ndarray,
+    interpret: bool | None,
 ) -> jnp.ndarray:
     """One fused scatter over ``(num_rows + 1)`` rows (last row = trash)."""
     nb = base.shape[0]
@@ -98,7 +99,7 @@ def _chain_call(
         ),
         out_shape=jax.ShapeDtypeStruct(base_p.shape, base_p.dtype),
         input_output_aliases={1: 0},  # alias `base_p` (arg after prefetch)
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(dest, base_p, blocks)
     return out[:nb]
 
@@ -109,7 +110,7 @@ def chain_delta_apply(
     blocks: jnp.ndarray,
     idx: jnp.ndarray,
     *,
-    interpret: bool = PALLAS_INTERPRET,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Apply a whole K-step sparse-delta chain to one blocked leaf.
 
@@ -136,7 +137,7 @@ def chain_delta_apply_batched(
     blocks: jnp.ndarray,
     idx: jnp.ndarray,
     *,
-    interpret: bool = PALLAS_INTERPRET,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Apply L independent delta chains to L same-sized leaves in ONE launch.
 

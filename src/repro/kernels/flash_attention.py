@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from . import PALLAS_INTERPRET
+from . import resolve_interpret
 
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
@@ -104,7 +104,7 @@ def flash_attention(
     softmax_scale: Optional[float] = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = PALLAS_INTERPRET,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     B, Sq, H, hd = q.shape
     _, Sk, KV, hd_v = v.shape
@@ -139,7 +139,7 @@ def flash_attention(
         out_specs=pl.BlockSpec((None, None, block_q, g, hd),
                                lambda bh, qi: (bh // KV, bh % KV, qi, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, KV, Sq, g, hd), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3, 4).reshape(B, Sq, H, hd)
 

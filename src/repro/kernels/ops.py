@@ -5,16 +5,16 @@ Converts arbitrary tensors (any dtype/shape) to and from the canonical
 operations the store uses:
 
 * :func:`to_blocks` / :func:`from_blocks` — byte-preserving (bitcast + pad)
-  layout conversion;
+  layout conversion, on the host for NumPy input and on the device for JAX
+  input;
 * :func:`xor_encode` / :func:`xor_apply` — the paper's XOR delta variant;
 * :func:`sparse_encode` / :func:`sparse_apply` — block-sparse delta:
   changed-block mask (Pallas), compaction to (idx, blocks), scattered apply
   (Pallas).  Capacity is rounded up to a power of two so jit recompiles stay
   bounded when the number of changed blocks varies between commits.
 
-Interpret mode is governed by the package-level
-:data:`repro.kernels.PALLAS_INTERPRET` knob (``REPRO_PALLAS_INTERPRET`` env
-var): interpret on this CPU container, compiled Mosaic on real TPU backends.
+Every kernel here is interpreted where the default backend is the CPU and
+compiled on a TPU (:func:`repro.kernels.resolve_interpret`).
 """
 
 from __future__ import annotations
@@ -27,14 +27,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import PALLAS_INTERPRET
 from .block_diff import block_hash, changed_block_mask, hash_coefficients
 from .chain_apply import chain_delta_apply, chain_delta_apply_batched
 from .ref import BLOCK_BYTES, BLOCK_ELEMS
 from .sparse_apply import sparse_delta_apply
 from .xor_delta import xor_delta
-
-INTERPRET = PALLAS_INTERPRET  # single env-controlled knob for all kernels
 
 
 @dataclasses.dataclass(frozen=True)
@@ -47,8 +44,15 @@ class BlockMeta:
     num_blocks: int
 
 
-def to_blocks(x: jnp.ndarray) -> Tuple[jnp.ndarray, BlockMeta]:
-    """View a tensor's bytes as (num_blocks, 8, 128) int32, zero-padded."""
+def to_blocks(x) -> Tuple[jnp.ndarray, BlockMeta]:
+    """View a tensor's bytes as (num_blocks, 8, 128) int32, zero-padded.
+
+    A NumPy array is viewed on the host and uploaded as int32 blocks, so
+    every dtype keeps every byte: with ``jax_enable_x64`` off (the default),
+    uploading a float64/int64 array first would narrow it to 32 bits.  A JAX
+    array is bitcast on the device."""
+    if isinstance(x, np.ndarray):
+        return _host_to_blocks(x)
     nbytes = x.size * x.dtype.itemsize
     flat_u8 = jax.lax.bitcast_convert_type(x.reshape(-1), jnp.uint8).reshape(-1)
     pad = (-nbytes) % BLOCK_BYTES
@@ -62,8 +66,23 @@ def to_blocks(x: jnp.ndarray) -> Tuple[jnp.ndarray, BlockMeta]:
     return as_i32, meta
 
 
-def from_blocks(blocks: jnp.ndarray, meta: BlockMeta) -> jnp.ndarray:
-    """Inverse of :func:`to_blocks`."""
+def _host_to_blocks(x: np.ndarray) -> Tuple[jnp.ndarray, BlockMeta]:
+    num_blocks = -(-x.nbytes // BLOCK_BYTES)
+    raw = np.ascontiguousarray(x).reshape(-1).view(np.uint8)
+    pad = num_blocks * BLOCK_BYTES - x.nbytes
+    if pad:
+        raw = np.concatenate([raw, np.zeros((pad,), np.uint8)])
+    blocks = jnp.asarray(raw.view(np.int32).reshape(num_blocks, 8, 128))
+    return blocks, BlockMeta(str(x.dtype), tuple(x.shape), x.nbytes, num_blocks)
+
+
+def from_blocks(blocks, meta: BlockMeta):
+    """Inverse of :func:`to_blocks`.  NumPy blocks (fetched from the device)
+    are viewed on the host, which any dtype survives; JAX blocks are bitcast
+    on the device and must hold a dtype the device has."""
+    if isinstance(blocks, np.ndarray):
+        flat = blocks.reshape(-1).view(np.uint8)[: meta.nbytes]
+        return flat.view(jnp.dtype(meta.dtype)).reshape(meta.shape)
     flat_u8 = jax.lax.bitcast_convert_type(
         blocks.reshape(-1), jnp.uint8
     ).reshape(-1)[: meta.nbytes]
@@ -78,21 +97,25 @@ def from_blocks(blocks: jnp.ndarray, meta: BlockMeta) -> jnp.ndarray:
 
 # ------------------------------------------------------------------ XOR delta
 def xor_encode(base_blocks: jnp.ndarray, new_blocks: jnp.ndarray) -> jnp.ndarray:
-    return xor_delta(base_blocks, new_blocks, interpret=INTERPRET)
+    return xor_delta(base_blocks, new_blocks)
 
 
 def xor_apply(base_blocks: jnp.ndarray, delta_blocks: jnp.ndarray) -> jnp.ndarray:
-    return xor_delta(base_blocks, delta_blocks, interpret=INTERPRET)
+    return xor_delta(base_blocks, delta_blocks)
 
 
 # ---------------------------------------------------------------- block hash
-# initialized at import: the concurrent serving tier calls block_hashes from
-# multiple threads, and a lazily-assigned global would race on first use
-_COEF = jnp.asarray(hash_coefficients())
+# built on first use, never at import (that would initialize a backend and,
+# on a TPU host, take the chip); functools.cache keeps the first use safe
+# under the serving tier's reader threads — concurrent first callers at worst
+# build equal arrays, and no caller sees a half-assigned global
+@functools.cache
+def _hash_coef() -> jnp.ndarray:
+    return jnp.asarray(hash_coefficients())
 
 
 def block_hashes(blocks: jnp.ndarray) -> jnp.ndarray:
-    return block_hash(blocks, _COEF, interpret=INTERPRET)[:, 0]
+    return block_hash(blocks, _hash_coef())[:, 0]
 
 
 # --------------------------------------------------------- block-sparse delta
@@ -144,7 +167,7 @@ def sparse_encode(
     Fully-traced callers should use ``_compact`` directly and branch on the
     returned count.
     """
-    mask = changed_block_mask(base_blocks, new_blocks, interpret=INTERPRET)
+    mask = changed_block_mask(base_blocks, new_blocks)
     if capacity is None:
         # one device→host sync on the commit path: the mask sum both sizes
         # the capacity and *is* the changed count, so _compact's (identical)
@@ -167,7 +190,7 @@ def sparse_encode(
 def sparse_apply(
     base_blocks: jnp.ndarray, packed_blocks: jnp.ndarray, idx: jnp.ndarray
 ) -> jnp.ndarray:
-    return sparse_delta_apply(base_blocks, packed_blocks, idx, interpret=INTERPRET)
+    return sparse_delta_apply(base_blocks, packed_blocks, idx)
 
 
 # ------------------------------------------------------------- chain apply
@@ -177,13 +200,11 @@ def chain_apply(
     """Fused K-step chain application (see :mod:`.chain_apply`): ``idx`` /
     ``packed_blocks`` stack K packed sparse deltas in chain order, flat or
     ``(K, capacity)``-shaped, padding ``idx < 0``."""
-    return chain_delta_apply(base_blocks, packed_blocks, idx, interpret=INTERPRET)
+    return chain_delta_apply(base_blocks, packed_blocks, idx)
 
 
 def chain_apply_batched(
     base_stack: jnp.ndarray, packed_blocks: jnp.ndarray, idx: jnp.ndarray
 ) -> jnp.ndarray:
     """Fused chain application for L same-sized leaves in one launch."""
-    return chain_delta_apply_batched(
-        base_stack, packed_blocks, idx, interpret=INTERPRET
-    )
+    return chain_delta_apply_batched(base_stack, packed_blocks, idx)

@@ -13,15 +13,13 @@ solver inner loops, and they compile to single-pass VMEM row reductions:
   the row kernels (vertex selection in Prim/MP, ρ-argmax in LMG).
 
 Each wrapper takes ``use_pallas``: ``True`` routes through the Pallas kernels
-(``interpret=True`` on this CPU container, matching the idiom of
-``kernels/ops.py``; flipped to compiled mode on real TPU backends), ``False``
-lowers the same reduction through plain XLA ops — the fast path on CPU, where
-the Pallas interpreter adds per-call overhead.  Both paths are bit-identical:
-the reductions are order-insensitive min/first-argmin over the same floats.
+(interpreted where the backend is the CPU, compiled on a TPU — see
+:func:`repro.kernels.resolve_interpret`), ``False`` lowers the same
+reduction through plain XLA ops — the fast path on CPU, where the Pallas
+interpreter adds per-call overhead.  Both paths are bit-identical: the
+reductions are order-insensitive min/first-argmin over the same floats.
 
-NOTE: solver costs are float64; the interpreter handles that everywhere, but
-real TPU lowering would need a float32 (or split hi/lo) variant.  Index math,
-by contrast, is int32 end to end: argmins use an explicit ``index_dtype`` and
+Index math is int32 end to end: argmins use an explicit ``index_dtype`` and
 the flat-index reconstruction in :func:`min_argmin_1d` guards its int32
 capacity host-side instead of relying on ``jax_enable_x64`` widening (which
 silently does not happen in the default production mode).
@@ -37,7 +35,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from . import PALLAS_INTERPRET
+from . import resolve_interpret
 
 DEFAULT_ROWS_PER_PROGRAM = 256
 LANE = 128  # pad the minor dim to the TPU lane width
@@ -45,8 +43,6 @@ LANE = 128  # pad the minor dim to the TPU lane width
 # largest flat vector min_argmin_1d can index with int32 math (padded length
 # r * LANE + col must not wrap); guarded host-side because shapes are static
 MAX_INT32_ELEMS = (1 << 31) - 1 - LANE
-
-INTERPRET = PALLAS_INTERPRET  # REPRO_PALLAS_INTERPRET env knob (kernels pkg)
 
 
 def _min_kernel(x_ref, o_ref):
@@ -60,7 +56,7 @@ def _argmin_kernel(x_ref, o_ref):
 
 
 def _row_call(kernel, x: jnp.ndarray, out_dtype, *, rows_per_program: int,
-              interpret: bool) -> jnp.ndarray:
+              interpret: bool | None) -> jnp.ndarray:
     nr, nc = x.shape
     rows = min(rows_per_program, nr)
     grid = (pl.cdiv(nr, rows),)
@@ -70,7 +66,7 @@ def _row_call(kernel, x: jnp.ndarray, out_dtype, *, rows_per_program: int,
         in_specs=[pl.BlockSpec((rows, nc), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rows, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nr, 1), out_dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)[:, 0]
 
 
@@ -84,7 +80,6 @@ def segment_min_rows(
     """Per-row minimum of a padded ``(rows, width)`` segment matrix."""
     if not use_pallas:
         return jnp.min(x, axis=1)
-    interpret = INTERPRET if interpret is None else interpret
     return _row_call(_min_kernel, x, x.dtype,
                      rows_per_program=rows_per_program, interpret=interpret)
 
@@ -99,7 +94,6 @@ def segment_argmin_rows(
     """Per-row index of the first minimum (NumPy ``argmin`` tie-breaking)."""
     if not use_pallas:
         return lax.argmin(x, 1, jnp.int32)
-    interpret = INTERPRET if interpret is None else interpret
     return _row_call(_argmin_kernel, x, jnp.int32,
                      rows_per_program=rows_per_program, interpret=interpret)
 

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import PALLAS_INTERPRET
+from . import resolve_interpret
 
 
 def _apply_kernel(idx_ref, base_ref, blocks_ref, o_ref):
@@ -36,7 +36,7 @@ def sparse_delta_apply(
     blocks: jnp.ndarray,
     idx: jnp.ndarray,
     *,
-    interpret: bool = PALLAS_INTERPRET,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Scatter ``blocks[k]`` into ``base[idx[k]]``; idx<0 rows are padding.
 
@@ -65,5 +65,5 @@ def sparse_delta_apply(
         ),
         out_shape=jax.ShapeDtypeStruct(base.shape, base.dtype),
         input_output_aliases={1: 0},  # alias `base` (arg after prefetch) to out
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(idx, base, blocks)

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from . import PALLAS_INTERPRET
+from . import resolve_interpret
 
 DEFAULT_ROWS_PER_PROGRAM = 256  # 256 blocks × 4 KiB × 3 streams = 3 MiB VMEM
 
@@ -33,7 +33,7 @@ def xor_delta(
     b: jnp.ndarray,
     *,
     rows_per_program: int = DEFAULT_ROWS_PER_PROGRAM,
-    interpret: bool = PALLAS_INTERPRET,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """a ^ b over (num_blocks, 8, 128) int32 block arrays."""
     assert a.shape == b.shape and a.dtype == b.dtype == jnp.int32, (a.shape, a.dtype)
@@ -51,5 +51,5 @@ def xor_delta(
         # (same-placed) write, so the delta can be built in place instead of
         # allocating a third full-shard HBM buffer
         input_output_aliases={0: 0},
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b)

@@ -256,9 +256,12 @@ class DatasetService:
             self._sweep_task = None
         self._dispatch_now()  # whatever the window was still holding
         while self._dispatch_tasks:
-            await asyncio.gather(
-                *list(self._dispatch_tasks), return_exceptions=True
-            )
+            tasks = list(self._dispatch_tasks)
+            await asyncio.gather(*tasks, return_exceptions=True)
+            # drop them here: a task that finished before this loop still
+            # has its discard callback queued, and gathering only finished
+            # tasks completes without yielding to the loop that would run it
+            self._dispatch_tasks.difference_update(tasks)
         # quiesce: taking the write side proves no reader remains in flight
         async with self._rw.write():
             pass

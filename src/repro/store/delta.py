@@ -119,8 +119,10 @@ def encode_delta(base: FlatTree, new: FlatTree) -> Tuple[bytes, Dict]:
             full[key] = _arr_to_wire(arr)
             stats["full_leaves"] += 1
             continue
-        bb, meta = ops.to_blocks(jnp.asarray(b))
-        nb, _ = ops.to_blocks(jnp.asarray(arr))
+        # NumPy in: the byte view happens on the host, so 64-bit leaves keep
+        # every byte (ops.to_blocks)
+        bb, meta = ops.to_blocks(b)
+        nb, _ = ops.to_blocks(arr)
         idx, blocks, n = ops.sparse_encode(bb, nb)
         stats["changed_blocks"] += n
         stats["total_blocks"] += int(bb.shape[0])
@@ -190,7 +192,7 @@ def apply_delta(base: FlatTree, payload: Union[bytes, DeltaWire]) -> FlatTree:
     # device results accumulate here and come back in ONE batched transfer
     # after the loop — a per-leaf np.asarray would serialize a device→host
     # sync per changed leaf (the analysis host-sync lint flags exactly that)
-    pending: Dict[str, jnp.ndarray] = {}
+    pending: Dict[str, Tuple[jnp.ndarray, "ops.BlockMeta"]] = {}
     for key, arr in base.items():
         if key in wire.tombstones:
             continue
@@ -198,11 +200,15 @@ def apply_delta(base: FlatTree, payload: Union[bytes, DeltaWire]) -> FlatTree:
         if d is None or d.n == 0:
             out[key] = arr
             continue
-        bb, meta = ops.to_blocks(jnp.asarray(arr))
+        bb, meta = ops.to_blocks(arr)
         rec = ops.sparse_apply(bb, jnp.asarray(d.blocks), jnp.asarray(d.idx))
-        pending[key] = ops.from_blocks(rec, meta)
+        pending[key] = (rec, meta)
     if pending:
-        out.update(jax.device_get(pending))
+        # blocks come back as int32 and are viewed as the leaf's dtype on the
+        # host, which 64-bit dtypes survive
+        fetched = jax.device_get({k: rec for k, (rec, _) in pending.items()})
+        for key, (_, meta) in pending.items():
+            out[key] = ops.from_blocks(fetched[key], meta)
     for key, wire_dict in wire.full.items():
         out[key] = _arr_from_wire(wire_dict)
     return out
@@ -326,10 +332,10 @@ def apply_delta_chains(
             if pre is not None:
                 origin_blocks, meta = pre
             else:
-                origin_blocks, meta = ops.to_blocks(jnp.asarray(base[u.key]))
+                origin_blocks, meta = ops.to_blocks(base[u.key])
         else:
             arr = _arr_from_wire(wire_chains[u.req][u.origin_step].full[u.key])
-            origin_blocks, meta = ops.to_blocks(jnp.asarray(arr))
+            origin_blocks, meta = ops.to_blocks(arr)
         origins[(u.req, u.key)] = (origin_blocks, meta)
         total = sum(s.n for s in u.segments)
         groups.setdefault((meta.num_blocks, _slot_bucket(total)), []).append(u)
@@ -337,7 +343,7 @@ def apply_delta_chains(
     # dispatch every group first, keeping results on device; the host copies
     # happen once at the end as a single batched transfer (device_get issues
     # the async copies together), not one blocking sync per leaf
-    host_fetch: List[Tuple[int, str, Any]] = []
+    host_fetch: List[Tuple[int, str, Any, "ops.BlockMeta"]] = []
     for (nb, cap), members in groups.items():
         idx_pad = np.full((len(members), cap), -1, np.int32)
         blk_pad = np.zeros((len(members), cap, 8, 128), np.int32)
@@ -367,11 +373,13 @@ def apply_delta_chains(
         for u, rec in zip(members, recs):
             meta = origins[(u.req, u.key)][1]
             blocked_outs[u.req][u.key] = (rec, meta)
-            host_fetch.append((u.req, u.key, ops.from_blocks(rec, meta)))
+            host_fetch.append((u.req, u.key, rec, meta))
     if host_fetch:
-        fetched = jax.device_get([dev for _, _, dev in host_fetch])
-        for (req, key, _), arr in zip(host_fetch, fetched):
-            outs[req][key] = arr
+        # int32 blocks come back and are viewed as each leaf's dtype on the
+        # host, which 64-bit dtypes survive
+        fetched = jax.device_get([rec for _, _, rec, _ in host_fetch])
+        for (req, key, _, meta), blocks in zip(host_fetch, fetched):
+            outs[req][key] = ops.from_blocks(blocks, meta)
     if _sp:
         if stats is not None:
             _sp.set(

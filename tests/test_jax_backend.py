@@ -14,6 +14,7 @@ The segment-op kernels themselves are unit-tested against NumPy reductions
 at the bottom.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -229,11 +230,9 @@ class TestSegmentOps:
 
     @pytest.mark.parametrize("use_pallas", [True, False])
     def test_row_min_matches_numpy(self, use_pallas):
-        from jax.experimental import enable_x64
-
         from repro.kernels.segment_ops import segment_min_rows
 
-        with enable_x64():
+        with jax.enable_x64(True):
             for seed in range(3):
                 x = self._rows(seed)
                 got = np.asarray(segment_min_rows(x, use_pallas=use_pallas))
@@ -241,11 +240,9 @@ class TestSegmentOps:
 
     @pytest.mark.parametrize("use_pallas", [True, False])
     def test_row_argmin_first_occurrence(self, use_pallas):
-        from jax.experimental import enable_x64
-
         from repro.kernels.segment_ops import segment_argmin_rows
 
-        with enable_x64():
+        with jax.enable_x64(True):
             x = self._rows(7)
             x[:, 3] = x[:, 11] = -500.0  # forced ties within every row
             got = np.asarray(segment_argmin_rows(x, use_pallas=use_pallas))
@@ -254,11 +251,9 @@ class TestSegmentOps:
     @pytest.mark.parametrize("use_pallas", [True, False])
     def test_min_argmin_1d(self, use_pallas):
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
-
         from repro.kernels.segment_ops import min_argmin_1d
 
-        with enable_x64():
+        with jax.enable_x64(True):
             rng = np.random.RandomState(0)
             for n in (1, 5, 128, 301):
                 x = rng.uniform(-10, 10, size=n)
@@ -271,11 +266,9 @@ class TestSegmentOps:
     @pytest.mark.parametrize("use_pallas", [True, False])
     def test_min_argmin_all_inf(self, use_pallas):
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
-
         from repro.kernels.segment_ops import min_argmin_1d
 
-        with enable_x64():
+        with jax.enable_x64(True):
             x = jnp.full((40,), jnp.inf)
             m, i = min_argmin_1d(x, use_pallas=use_pallas)
             assert int(i) == 0 and not np.isfinite(float(m))

@@ -383,6 +383,40 @@ class TestFusedPipeline:
         want = VersionStore(tmp_path, cache_budget_bytes=0).checkout(vids[-1])
         assert_trees_equal(trees[vids[-1]], want)
 
+    @pytest.mark.parametrize("budget", [0, 256 << 20])
+    @pytest.mark.parametrize("fuse", [True, False])
+    def test_64bit_leaves_bit_exact(self, tmp_path, fuse, budget):
+        # float64/int64 leaves (NumPy's defaults for dataset columns) keep
+        # every byte through commit -> delta -> checkout; with x64 off, an
+        # upload before the byte view would narrow them to 32 bits.  The 0-d
+        # step counter must keep its shape through the host byte view too
+        rng = np.random.RandomState(3)
+        col = rng.randn(5000)
+        ids = rng.randint(2**40, 2**50, size=3000, dtype=np.int64)
+        store = VersionStore(
+            tmp_path, cache_budget_bytes=budget, fuse_chains=fuse
+        )
+        trees, vids = [], []
+        for step in range(4):
+            col, ids = col.copy(), ids.copy()
+            if step:
+                # changes a float32 or int32 copy could not represent
+                col[rng.randint(col.size, size=3)] *= 1 + 1e-12
+                ids[rng.randint(ids.size, size=3)] += 1
+            step_ctr = np.array(2**40 + step, np.int64)
+            trees.append({"col": col, "ids": ids, "step": step_ctr})
+            vids.append(store.commit(trees[-1], parents=vids[-1:]))
+        assert all(store.versions[v].stored_base is not None for v in vids[1:])
+        cold = VersionStore(
+            tmp_path, cache_budget_bytes=budget, fuse_chains=fuse
+        )
+        # the tip cold (whole chain), then every version in one batch
+        got = [cold.checkout(vids[-1])] + cold.checkout_many(vids)
+        for tree, want in zip(got, trees[-1:] + trees):
+            for k in want:
+                assert tree[k].dtype == want[k].dtype, k
+                assert np.array_equal(tree[k], want[k]), k
+
     def test_fused_trees_frozen_and_cached(self, tmp_path):
         store = VersionStore(tmp_path, fuse_chains=True)
         vids, _ = build_linear_history(store, n=4, shape=(64, 64))

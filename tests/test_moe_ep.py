@@ -5,6 +5,7 @@ subprocess with forced host devices (the main pytest process has already
 locked jax to 1 device).
 """
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -22,11 +23,8 @@ SCRIPT = textwrap.dedent("""
     from repro.models import moe as moe_lib
     from repro.models import moe_ep
 
-    if hasattr(jax.sharding, "AxisType"):
-        mesh = jax.make_mesh((2, 4), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    else:  # older jax: axes are Auto by default and axis_types doesn't exist
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
     cfg = dataclasses.replace(
         ARCHS["phi3.5-moe-42b-a6.6b"].reduced(),
@@ -82,7 +80,10 @@ def test_ep_matches_dispatch_on_8dev_mesh():
     out = subprocess.run(
         [sys.executable, "-c", SCRIPT],
         capture_output=True, text=True, timeout=600,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
+        # the child runs on host devices like the tests themselves: keep the
+        # parent's environment, JAX_PLATFORMS=cpu included, so it never
+        # reaches for an accelerator
+        env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"},
     )
     assert out.returncode == 0, f"stdout:\n{out.stdout}\nstderr:\n{out.stderr[-3000:]}"
     assert "EP==dispatch OK" in out.stdout

@@ -19,6 +19,7 @@ No pytest-asyncio in the image: each test drives its own event loop via
 """
 
 import asyncio
+import threading
 
 import numpy as np
 import pytest
@@ -234,6 +235,24 @@ class TestWriteCoordination:
                 await svc2.checkout("main")
 
         asyncio.run(after())
+
+    def test_stop_right_after_a_coalesced_batch_returns(self, tmp_path):
+        # the batch's dispatch task can finish before its done callback
+        # (which drops it from the task set) runs; stop() must not spin on
+        # it.  A spinning stop never yields, so the bound is a thread join
+        repo, trees = build_repo(tmp_path, versions=3)
+
+        async def go():
+            async with repo.serve() as svc:
+                return await svc.checkout_many(["main", 1, 2, 3])
+
+        out = []
+        t = threading.Thread(target=lambda: out.append(asyncio.run(go())),
+                             daemon=True)
+        t.start()
+        t.join(timeout=60)
+        assert not t.is_alive(), "stop() did not return"
+        assert np.array_equal(out[0][0]["w"], trees[3]["w"])
 
 
 class TestCancellation:
