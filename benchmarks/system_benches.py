@@ -39,7 +39,8 @@ def kernel_throughput() -> List[Row]:
             repeats=3)
         rows.append(Row(f"kernel/mask/{nbytes>>20}MiB", us,
                         f"GBps_interpret={2*nbytes/us/1e3:.3f}"))
-        idx, blocks, n = ops.sparse_encode(a, b)
+        mask, n = ops.count_changed(a, b)
+        idx, blocks = ops.compact(mask, b, n)
         out, us = timed(lambda: ops.sparse_apply(a, blocks, idx).block_until_ready(),
                         repeats=3)
         rows.append(Row(f"kernel/sparse_apply/{nbytes>>20}MiB", us,

@@ -501,7 +501,7 @@ def bucket_probes() -> List[BucketProbe]:
     return [
         BucketProbe("kernels.segment_ops.min_argmin_1d/pad_to_rows",
                     probe_min_argmin, (27, 100, 128)),
-        BucketProbe("kernels.ops.sparse_encode/_round_capacity",
+        BucketProbe("kernels.ops.compact/_round_capacity",
                     probe_compact, (5, 6, 8)),
         BucketProbe("store.delta.apply_delta_chains/_slot_bucket",
                     probe_chain, (3, 5, 8)),

@@ -8,10 +8,12 @@ operations the store uses:
   layout conversion, on the host for NumPy input and on the device for JAX
   input;
 * :func:`xor_encode` / :func:`xor_apply` — the paper's XOR delta variant;
-* :func:`sparse_encode` / :func:`sparse_apply` — block-sparse delta:
-  changed-block mask (Pallas), compaction to (idx, blocks), scattered apply
-  (Pallas).  Capacity is rounded up to a power of two so jit recompiles stay
-  bounded when the number of changed blocks varies between commits.
+* :func:`count_changed` / :func:`compact` / :func:`sparse_apply` —
+  block-sparse delta: changed-block mask (Pallas) and its count,
+  compaction to (idx, blocks), scattered apply (Pallas).  Capacity is
+  rounded up to a power of two so jit recompiles stay bounded when the
+  number of changed blocks varies between commits; :func:`sparse_encode`
+  compacts into a capacity the caller fixes.
 
 Every kernel here is interpreted where the default backend is the CPU and
 compiled on a TPU (:func:`repro.kernels.resolve_interpret`).
@@ -153,36 +155,49 @@ def _compact(mask: jnp.ndarray, new_blocks: jnp.ndarray, capacity: int):
     return idx, gathered, jnp.sum(m, dtype=jnp.int32)
 
 
-def sparse_encode(
-    base_blocks: jnp.ndarray, new_blocks: jnp.ndarray, *, capacity: int | None = None
-) -> Tuple[jnp.ndarray, jnp.ndarray, int]:
-    """Return (idx, packed_blocks, n_changed) for the block-sparse delta.
+def count_changed(
+    base_blocks: jnp.ndarray, new_blocks: jnp.ndarray
+) -> Tuple[jnp.ndarray, int]:
+    """The changed-block mask and the changed count, on the host.
 
-    With ``capacity=None`` the exact changed count is materialized host-side
-    (store/commit path, off the step-critical path) and capacity grows to
-    fit.  An explicit ``capacity`` keeps jit recompiles bounded, but must
-    cover the changed count: an undersized capacity would silently drop
-    changed blocks in ``_compact`` (producing a delta that ``sparse_apply``
-    cannot detect as corrupt), so this host-side wrapper raises instead.
-    Fully-traced callers should use ``_compact`` directly and branch on the
-    returned count.
+    The one device→host sync of a diff: the mask sum both sizes the
+    capacity and *is* the changed count, so :func:`_compact`'s (identical)
+    device-side count is never materialized host-side."""
+    mask = changed_block_mask(base_blocks, new_blocks)
+    return mask, int(jnp.sum(mask[:, 0]))
+
+
+def compact(
+    mask: jnp.ndarray, new_blocks: jnp.ndarray, n: int
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(idx, blocks)`` of the ``n`` changed rows, in a capacity rounded up
+    to a power of two (padded past ``n``; see :func:`_compact`)."""
+    idx, blocks, _ = _compact(mask, new_blocks, _round_capacity(max(1, n)))
+    return idx, blocks
+
+
+def sparse_encode(
+    base_blocks: jnp.ndarray, new_blocks: jnp.ndarray, *, capacity: int
+) -> Tuple[jnp.ndarray, jnp.ndarray, int]:
+    """Return (idx, packed_blocks, n_changed) for the block-sparse delta in
+    a fixed ``capacity``, which keeps jit recompiles bounded.
+
+    The capacity must cover the changed count: an undersized capacity would
+    silently drop changed blocks in ``_compact`` (producing a delta that
+    ``sparse_apply`` cannot detect as corrupt), so this host-side wrapper
+    raises instead.  To size the capacity from the exact count (the commit
+    path), use :func:`count_changed` then :func:`compact`.  Fully-traced
+    callers should use ``_compact`` directly and branch on the returned
+    count.
     """
     mask = changed_block_mask(base_blocks, new_blocks)
-    if capacity is None:
-        # one device→host sync on the commit path: the mask sum both sizes
-        # the capacity and *is* the changed count, so _compact's (identical)
-        # device-side count is never materialized host-side
-        n = int(jnp.sum(mask[:, 0]))
-        capacity = _round_capacity(max(1, n))
-        idx, blocks, _ = _compact(mask, new_blocks, capacity)
-        return idx, blocks, n
     idx, blocks, n_dev = _compact(mask, new_blocks, capacity)
     n = int(n_dev)
     if n > capacity:
         raise ValueError(
             f"sparse_encode capacity overflow: {n} changed blocks exceed "
             f"capacity={capacity}; pass capacity>={_round_capacity(n)} (or "
-            f"capacity=None to size automatically)"
+            f"size it with count_changed and compact)"
         )
     return idx, blocks, n
 

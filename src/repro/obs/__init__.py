@@ -28,7 +28,7 @@ Quick start::
         ...  # run service traffic
     obs.chrome_trace(tracer, "trace.json")   # load in ui.perfetto.dev
 
-CLI: ``python -m repro.obs {summary,trace,convert,prom,overhead}``
+CLI: ``python -m repro.obs {summary,trace,prom,overhead}``
 (``--synthetic`` self-exercises a throwaway store end to end).
 """
 
@@ -39,8 +39,6 @@ from typing import Iterator
 
 from .export import (
     chrome_trace,
-    dump_spans_jsonl,
-    load_spans_jsonl,
     prometheus_text,
     validate_chrome_trace,
 )
@@ -71,8 +69,6 @@ __all__ = [
     "chrome_trace",
     "validate_chrome_trace",
     "prometheus_text",
-    "dump_spans_jsonl",
-    "load_spans_jsonl",
     "TradeoffMonitor",
     "TradeoffSample",
 ]

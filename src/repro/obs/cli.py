@@ -1,4 +1,4 @@
-"""Command-line front-end: ``python -m repro.obs {summary,trace,convert,prom,overhead}``.
+"""Command-line front-end: ``python -m repro.obs {summary,trace,prom,overhead}``.
 
 * ``summary`` — per-span-name rollup table (count / total / mean / max) plus
   the live tradeoff snapshot; ``--synthetic`` builds a throwaway store and
@@ -9,8 +9,6 @@
   structurally broken export fails the command), ``--prom`` writes the
   Prometheus text exposition.
 * ``trace OUT`` — synthetic exercise, write only the Chrome trace.
-* ``convert IN OUT`` — spans JSONL (``dump_spans_jsonl`` format) → Chrome
-  trace JSON, validated.
 * ``prom`` — synthetic exercise, print the Prometheus exposition.
 * ``overhead`` — the disabled-tracer overhead gate: measures warm-checkout
   latency, counts instrumentation points hit per warm checkout, measures
@@ -33,13 +31,7 @@ import tempfile
 import time
 from typing import Any, Dict, Optional, Tuple
 
-from .export import (
-    chrome_trace,
-    dump_spans_jsonl,
-    load_spans_jsonl,
-    prometheus_text,
-    validate_chrome_trace,
-)
+from .export import chrome_trace, prometheus_text, validate_chrome_trace
 from .tracer import Tracer, get_tracer, set_tracer, span as _span
 
 
@@ -155,8 +147,7 @@ def _cmd_summary(args: argparse.Namespace) -> int:
     if not args.synthetic:
         print(
             "summary: only --synthetic mode is available from the CLI (a "
-            "live tracer exists only inside the traced process; export one "
-            "with dump_spans_jsonl and use 'convert')",
+            "live tracer exists only inside the traced process)",
             file=sys.stderr,
         )
         return 2
@@ -183,23 +174,7 @@ def _cmd_summary(args: argparse.Namespace) -> int:
 
 def _cmd_trace(args: argparse.Namespace) -> int:
     tracer, _ = _exercise(args)
-    if args.spans_out:
-        n = dump_spans_jsonl(tracer, args.spans_out)
-        print(f"wrote {args.spans_out} ({n} spans, JSONL)")
     return _write_trace(tracer, args.out)
-
-
-def _cmd_convert(args: argparse.Namespace) -> int:
-    rows = load_spans_jsonl(args.inp)
-    chrome_trace(rows, args.out)
-    problems = validate_chrome_trace(args.out)
-    if problems:
-        print(f"convert: output failed validation:", file=sys.stderr)
-        for p in problems:
-            print(f"  - {p}", file=sys.stderr)
-        return 1
-    print(f"converted {len(rows)} spans -> {args.out}")
-    return 0
 
 
 def _cmd_prom(args: argparse.Namespace) -> int:
@@ -299,13 +274,6 @@ def main(argv: Optional[list] = None) -> int:
 
     s = sub.add_parser("trace", help="synthetic exercise -> Chrome trace")
     s.set_defaults(fn=_cmd_trace, synthetic=True)
-    s.add_argument("out", help="Chrome trace JSON output path")
-    s.add_argument("--spans-out", default=None, metavar="PATH",
-                   help="also dump raw spans as JSONL ('convert' input)")
-
-    s = sub.add_parser("convert", help="spans JSONL -> Chrome trace JSON")
-    s.set_defaults(fn=_cmd_convert)
-    s.add_argument("inp", help="spans JSONL (dump_spans_jsonl format)")
     s.add_argument("out", help="Chrome trace JSON output path")
 
     s = sub.add_parser("prom", help="synthetic exercise -> Prometheus text")

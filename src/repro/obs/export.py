@@ -30,7 +30,7 @@ from __future__ import annotations
 import json
 import re
 from pathlib import Path
-from typing import Any, Dict, Iterable, List, Optional, Sequence, Union
+from typing import Any, Dict, Iterable, List, Optional, Union
 
 from .tracer import Span, Tracer
 
@@ -38,19 +38,11 @@ __all__ = [
     "chrome_trace",
     "validate_chrome_trace",
     "prometheus_text",
-    "dump_spans_jsonl",
-    "load_spans_jsonl",
 ]
-
-_SpanLike = Union[Span, Dict[str, Any]]
-
-
-def _as_dict(sp: _SpanLike) -> Dict[str, Any]:
-    return sp.to_dict() if isinstance(sp, Span) else sp
 
 
 def chrome_trace(
-    spans: Union[Tracer, Iterable[_SpanLike]],
+    spans: Union[Tracer, Iterable[Span]],
     path: Union[str, Path, None] = None,
     *,
     pid: int = 1,
@@ -63,10 +55,17 @@ def chrome_trace(
     ``base`` merges these events into an existing export (pass the previous
     call's return value with a different ``pid`` to put several runs in one
     file — each shows up as its own process group in Perfetto).
+
+    This export holds every span on the tracer clock.  A JAX profiler
+    trace (``.xplane.pb``) taken at the same time holds only the spans
+    entered with ``with`` on a thread track (the store, delta codec,
+    object store, materializer and tradeoff spans), on the device's clock;
+    the ``svc.*`` request spans, explicit ``start``/``end`` spans and
+    ``add_event`` spans appear here only (see :mod:`.tracer`).
     """
     if isinstance(spans, Tracer):
         spans = spans.spans()
-    rows = [_as_dict(s) for s in spans]
+    rows = [s.to_dict() for s in spans]
     rows = [r for r in rows if r.get("t1") is not None]
     rows.sort(key=lambda r: r["t0"])
 
@@ -274,28 +273,3 @@ def prometheus_text(snapshot: Dict[str, Any]) -> str:
                 "gauge",
             )
     return "\n".join(lines) + "\n"
-
-
-# -- raw span dump / convert -------------------------------------------------
-def dump_spans_jsonl(
-    spans: Union[Tracer, Sequence[_SpanLike]], path: Union[str, Path]
-) -> int:
-    """Write spans as one-JSON-object-per-line (the ``convert`` input
-    format); returns the number written."""
-    if isinstance(spans, Tracer):
-        spans = spans.spans()
-    rows = [_as_dict(s) for s in spans]
-    with open(path, "w") as f:
-        for r in rows:
-            f.write(json.dumps(r, separators=(",", ":")) + "\n")
-    return len(rows)
-
-
-def load_spans_jsonl(path: Union[str, Path]) -> List[Dict[str, Any]]:
-    rows = []
-    with open(path) as f:
-        for line in f:
-            line = line.strip()
-            if line:
-                rows.append(json.loads(line))
-    return rows

@@ -23,6 +23,14 @@ The **span contract** every instrumented layer follows:
   an asyncio task get a per-task track (concurrent requests don't
   interleave on one Perfetto row); spans opened elsewhere get their
   thread's track.
+* One clock with the device: a span entered with ``with`` on a thread
+  track also enters a ``jax.profiler.TraceAnnotation`` of its name, so a
+  running JAX profiler records it on the ``/host:CPU`` plane, on the
+  nanosecond clock of the device events.  Spans on a task track (the
+  service's request spans, which open and close across event-loop tasks,
+  where a per-thread annotation cannot nest), spans closed with ``end()``
+  and ``add_event`` spans stay on the tracer clock only.  ``t0``/``t1``
+  are on the tracer clock either way.
 
 Disabled tracers (the default global) are near-free: ``span()``/``start``
 return a shared no-op singleton whose methods do nothing, and
@@ -42,12 +50,11 @@ from __future__ import annotations
 import asyncio
 import contextlib
 import contextvars
-import functools
 import itertools
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterator, List, Optional
 
 __all__ = [
     "Span",
@@ -84,7 +91,7 @@ class Span:
 
     __slots__ = (
         "name", "span_id", "parent_id", "trace_id", "t0", "t1",
-        "attrs", "track", "_tracer", "_token",
+        "attrs", "track", "_tracer", "_token", "_annotation",
     )
 
     def __init__(
@@ -108,6 +115,7 @@ class Span:
         self.track = track
         self._tracer = tracer
         self._token: Optional[contextvars.Token] = None
+        self._annotation: Any = None
 
     def set(self, **attrs: Any) -> "Span":
         """Attach attributes (merged; later calls win on key collision)."""
@@ -129,12 +137,21 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _current_span.set(self)
+        if not self.track.startswith("task:"):
+            # imported here: a disabled tracer never touches JAX
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation(self.name)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc: Any) -> None:
         if self._token is not None:
             _current_span.reset(self._token)
             self._token = None
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         self.end()
 
     def __bool__(self) -> bool:
@@ -145,7 +162,7 @@ class Span:
         return f"<Span {self.name!r} #{self.span_id} {state}>"
 
     def to_dict(self) -> Dict[str, Any]:
-        """JSON-serializable form (the JSONL dump / ``convert`` format)."""
+        """JSON-serializable form (what the Chrome trace export reads)."""
         return {
             "name": self.name,
             "span_id": self.span_id,
@@ -286,30 +303,6 @@ class Tracer:
             yield
         finally:
             _current_span.reset(token)
-
-    def wrap(self, name: Optional[str] = None, **attrs: Any) -> Callable:
-        """Decorator form: traces every call of the wrapped (a)sync function
-        as one span named ``name`` (default: the function's qualname)."""
-
-        def deco(fn: Callable) -> Callable:
-            label = name or fn.__qualname__
-            if asyncio.iscoroutinefunction(fn):
-
-                @functools.wraps(fn)
-                async def awrapper(*args: Any, **kwargs: Any):
-                    with self.span(label, **attrs):
-                        return await fn(*args, **kwargs)
-
-                return awrapper
-
-            @functools.wraps(fn)
-            def wrapper(*args: Any, **kwargs: Any):
-                with self.span(label, **attrs):
-                    return fn(*args, **kwargs)
-
-            return wrapper
-
-        return deco
 
     # -- ring buffer -------------------------------------------------------
     def _record(self, sp: Span) -> None:

@@ -36,6 +36,8 @@ import threading
 import time
 from typing import Any, Deque, Dict, List, Optional
 
+from .tracer import span
+
 __all__ = ["TradeoffMonitor", "TradeoffSample"]
 
 
@@ -122,6 +124,10 @@ class TradeoffMonitor:
 
     def sample(self, event: str = "sample") -> TradeoffSample:
         """Measure now, append to history, and return the sample."""
+        with span("tradeoff.sample", event=event):
+            return self._sample(event)
+
+    def _sample(self, event: str) -> TradeoffSample:
         from ..service.metrics import percentile  # local: leaf-only import
 
         store = self.store

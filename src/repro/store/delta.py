@@ -48,7 +48,7 @@ import numpy as np
 
 from ..kernels import ops
 from ..kernels.ref import BLOCK_BYTES
-from ..obs.tracer import start as _trace_start
+from ..obs.tracer import span as _span
 
 FlatTree = Dict[str, np.ndarray]
 # device-resident companion of a FlatTree: blocked form + layout meta per
@@ -96,49 +96,85 @@ def encode_full(flat: FlatTree) -> bytes:
     # the commit path (tree_flatten order) and checkout-re-encode
     # (apply_delta rebuild order) — an order-dependent fp would spuriously
     # invalidate the incremental Δ/Φ edge cache
-    return msgpack.packb(
-        {"kind": "full",
-         "leaves": {k: _arr_to_wire(flat[k]) for k in sorted(flat)}},
-        use_bin_type=True,
-    )
+    with _span("delta.encode_full"):
+        return msgpack.packb(
+            {"kind": "full",
+             "leaves": {k: _arr_to_wire(flat[k]) for k in sorted(flat)}},
+            use_bin_type=True,
+        )
 
 
 def decode_full(payload: bytes) -> FlatTree:
-    obj = msgpack.unpackb(payload, raw=False)
-    assert obj["kind"] == "full", obj["kind"]
-    return {k: _arr_from_wire(v) for k, v in obj["leaves"].items()}
+    with _span("delta.decode_full"):
+        obj = msgpack.unpackb(payload, raw=False)
+        assert obj["kind"] == "full", obj["kind"]
+        return {k: _arr_from_wire(v) for k, v in obj["leaves"].items()}
 
 
 def encode_delta(base: FlatTree, new: FlatTree) -> Tuple[bytes, Dict]:
-    """Delta payload turning `base` into `new`, plus stats for Φ modelling."""
-    sparse, full, stats = {}, {}, {"changed_blocks": 0, "total_blocks": 0, "full_leaves": 0}
-    tombstones = [k for k in base if k not in new]
-    for key, arr in new.items():
-        b = base.get(key)
-        if b is None or b.shape != arr.shape or b.dtype != arr.dtype:
-            full[key] = _arr_to_wire(arr)
-            stats["full_leaves"] += 1
-            continue
-        # NumPy in: the byte view happens on the host, so 64-bit leaves keep
-        # every byte (ops.to_blocks)
-        bb, meta = ops.to_blocks(b)
-        nb, _ = ops.to_blocks(arr)
-        idx, blocks, n = ops.sparse_encode(bb, nb)
-        stats["changed_blocks"] += n
-        stats["total_blocks"] += int(bb.shape[0])
-        if n == 0:
-            sparse[key] = {"idx": b"", "blocks": b"", "n": 0}
-            continue
-        # trim padding before serialization (padding is a device-side artifact)
-        sparse[key] = {
-            "idx": np.asarray(idx[:n], np.int32).tobytes(),
-            "blocks": np.asarray(blocks[:n], np.int32).tobytes(),
-            "n": int(n),
-        }
-    payload = msgpack.packb(
-        {"kind": "delta", "sparse": sparse, "full": full, "tombstones": tombstones},
-        use_bin_type=True,
-    )
+    """Delta payload turning `base` into `new`, plus stats for Φ modelling.
+
+    Traced as ``delta.encode_delta``; for each leaf diffed on the device,
+    ``delta.upload`` (host staging and the *enqueue* of both leaves'
+    transfers: it ends before the device holds them), ``delta.count`` (the
+    mask and its changed count: it ends at the count's sync, so it absorbs
+    the device's wait for the upload and the mask) and ``delta.fetch``
+    (compaction and the downloads of the changed rows); then
+    ``delta.pack``.  The span's attributes count what crossed between host
+    and device, at the transfer sites: ``h2d_bytes`` (the blocks uploaded
+    from host leaves), ``d2h_bytes`` (each int32 changed count and the
+    fetched ``idx[:n]`` and ``blocks[:n]``) and ``changed_blocks``.
+    """
+    with _span("delta.encode_delta") as sp:
+        sparse, full = {}, {}
+        stats = {"changed_blocks": 0, "total_blocks": 0, "full_leaves": 0}
+        h2d = d2h = 0
+        tombstones = [k for k in base if k not in new]
+        for key, arr in new.items():
+            b = base.get(key)
+            if b is None or b.shape != arr.shape or b.dtype != arr.dtype:
+                full[key] = _arr_to_wire(arr)
+                stats["full_leaves"] += 1
+                continue
+            # NumPy in: the byte view happens on the host, so 64-bit leaves
+            # keep every byte (ops.to_blocks)
+            with _span("delta.upload"):
+                bb, _ = ops.to_blocks(b)
+                nb, _ = ops.to_blocks(arr)
+            with _span("delta.count"):
+                mask, n = ops.count_changed(bb, nb)
+            stats["changed_blocks"] += n
+            stats["total_blocks"] += int(bb.shape[0])
+            if sp:
+                # a device leaf is blocked on the device: nothing crosses
+                h2d += sum(blk.nbytes for blk, x in ((bb, b), (nb, arr))
+                           if isinstance(x, np.ndarray))
+                d2h += 4  # the int32 count
+            if n == 0:
+                sparse[key] = {"idx": b"", "blocks": b"", "n": 0}
+                continue
+            with _span("delta.fetch"):
+                idx, blocks = ops.compact(mask, nb, n)
+                # trim padding before serialization (padding is a
+                # device-side artifact)
+                host_idx = np.asarray(idx[:n], np.int32)
+                host_blocks = np.asarray(blocks[:n], np.int32)
+                sparse[key] = {
+                    "idx": host_idx.tobytes(),
+                    "blocks": host_blocks.tobytes(),
+                    "n": int(n),
+                }
+            if sp:
+                d2h += host_idx.nbytes + host_blocks.nbytes
+        with _span("delta.pack"):
+            payload = msgpack.packb(
+                {"kind": "delta", "sparse": sparse, "full": full,
+                 "tombstones": tombstones},
+                use_bin_type=True,
+            )
+        if sp:
+            sp.set(h2d_bytes=h2d, d2h_bytes=d2h,
+                   changed_blocks=stats["changed_blocks"])
     return payload, stats
 
 
@@ -288,108 +324,125 @@ def apply_delta_chains(
     Returns ``[(tree, blocked)]`` per request, bit-identical to folding
     :func:`apply_delta` over each chain.  ``stats`` (optional) is bumped
     with ``launches`` / ``fused_slots`` for observability.
+
+    Traced as ``delta.apply_chains``, with ``h2d_bytes`` (origin leaves
+    uploaded to block form, and the padded slot stacks) and ``d2h_bytes``
+    (the fetched result blocks) besides the launch counts.
     """
-    # explicit-lifetime span (no context entry): nothing below opens child
-    # spans, and the single end() keeps the device-dispatch loop unindented
-    _sp = _trace_start("delta.apply_chains", requests=len(requests))
-    # the caller's stats dict accumulates across calls; snapshot so the span
-    # attributes only this call's launches
-    _launch0 = (stats or {}).get("launches", 0)
-    _slots0 = (stats or {}).get("fused_slots", 0)
-    wire_chains: List[List[DeltaWire]] = []
-    outs: List[FlatTree] = []
-    blocked_outs: List[BlockedTree] = []
-    units: List[_LeafProgram] = []
-    for ri, (base, payloads, _) in enumerate(requests):
-        wires = [
-            decode_delta_wire(p) if isinstance(p, bytes) else p
-            for p in payloads
-        ]
-        wire_chains.append(wires)
-        out: FlatTree = {}
-        outs.append(out)
-        blocked_outs.append({})
-        for key, (origin_step, segs) in _resolve_leaf_programs(
-            base, wires
-        ).items():
-            if not segs:
-                # untouched leaf (reference passthrough) or plain full decode
-                out[key] = (
-                    base[key]
-                    if origin_step is None
-                    else _arr_from_wire(wires[origin_step].full[key])
-                )
-                continue
-            units.append(_LeafProgram(ri, key, origin_step, segs))
+    with _span("delta.apply_chains", requests=len(requests)) as sp:
+        # the caller's stats dict accumulates across calls; snapshot so the
+        # span attributes only this call's launches
+        launch0 = (stats or {}).get("launches", 0)
+        slots0 = (stats or {}).get("fused_slots", 0)
+        h2d = d2h = 0
+        wire_chains: List[List[DeltaWire]] = []
+        outs: List[FlatTree] = []
+        blocked_outs: List[BlockedTree] = []
+        units: List[_LeafProgram] = []
+        for ri, (base, payloads, _) in enumerate(requests):
+            wires = [
+                decode_delta_wire(p) if isinstance(p, bytes) else p
+                for p in payloads
+            ]
+            wire_chains.append(wires)
+            out: FlatTree = {}
+            outs.append(out)
+            blocked_outs.append({})
+            for key, (origin_step, segs) in _resolve_leaf_programs(
+                base, wires
+            ).items():
+                if not segs:
+                    # untouched leaf (reference passthrough) or plain full
+                    # decode
+                    out[key] = (
+                        base[key]
+                        if origin_step is None
+                        else _arr_from_wire(wires[origin_step].full[key])
+                    )
+                    continue
+                units.append(_LeafProgram(ri, key, origin_step, segs))
 
-    # shape-bucketed grouping: one fused launch per (num_blocks, slot_bucket)
-    groups: Dict[Tuple[int, int], List[_LeafProgram]] = {}
-    origins: Dict[Tuple[int, str], Tuple[Any, "ops.BlockMeta"]] = {}
-    for u in units:
-        base, _, base_blocked = requests[u.req]
-        if u.origin_step is None:
-            pre = (base_blocked or {}).get(u.key)
-            if pre is not None:
-                origin_blocks, meta = pre
+        # shape-bucketed grouping: one fused launch per (num_blocks,
+        # slot_bucket)
+        groups: Dict[Tuple[int, int], List[_LeafProgram]] = {}
+        origins: Dict[Tuple[int, str], Tuple[Any, "ops.BlockMeta"]] = {}
+        for u in units:
+            base, _, base_blocked = requests[u.req]
+            if u.origin_step is None:
+                pre = (base_blocked or {}).get(u.key)
+                if pre is not None:
+                    origin_blocks, meta = pre
+                else:
+                    origin_blocks, meta = ops.to_blocks(base[u.key])
+                    if sp and isinstance(base[u.key], np.ndarray):
+                        h2d += origin_blocks.nbytes
             else:
-                origin_blocks, meta = ops.to_blocks(base[u.key])
-        else:
-            arr = _arr_from_wire(wire_chains[u.req][u.origin_step].full[u.key])
-            origin_blocks, meta = ops.to_blocks(arr)
-        origins[(u.req, u.key)] = (origin_blocks, meta)
-        total = sum(s.n for s in u.segments)
-        groups.setdefault((meta.num_blocks, _slot_bucket(total)), []).append(u)
+                arr = _arr_from_wire(
+                    wire_chains[u.req][u.origin_step].full[u.key]
+                )
+                origin_blocks, meta = ops.to_blocks(arr)
+                if sp:
+                    h2d += origin_blocks.nbytes
+            origins[(u.req, u.key)] = (origin_blocks, meta)
+            total = sum(s.n for s in u.segments)
+            groups.setdefault(
+                (meta.num_blocks, _slot_bucket(total)), []
+            ).append(u)
 
-    # dispatch every group first, keeping results on device; the host copies
-    # happen once at the end as a single batched transfer (device_get issues
-    # the async copies together), not one blocking sync per leaf
-    host_fetch: List[Tuple[int, str, Any, "ops.BlockMeta"]] = []
-    for (nb, cap), members in groups.items():
-        idx_pad = np.full((len(members), cap), -1, np.int32)
-        blk_pad = np.zeros((len(members), cap, 8, 128), np.int32)
-        for li, u in enumerate(members):
-            at = 0
-            for seg in u.segments:  # chain order: later slots win in the fold
-                idx_pad[li, at : at + seg.n] = seg.idx
-                blk_pad[li, at : at + seg.n] = seg.blocks
-                at += seg.n
-        if len(members) == 1:
-            u = members[0]
-            ob, meta = origins[(u.req, u.key)]
-            rec = ops.chain_apply(
-                ob, jnp.asarray(blk_pad[0]), jnp.asarray(idx_pad[0])
-            )
-            recs = [rec]
-        else:
-            stack = jnp.stack([origins[(u.req, u.key)][0] for u in members])
-            recs = ops.chain_apply_batched(
-                stack, jnp.asarray(blk_pad), jnp.asarray(idx_pad)
-            )
-        if stats is not None:
-            stats["launches"] = stats.get("launches", 0) + 1
-            stats["fused_slots"] = (
-                stats.get("fused_slots", 0) + len(members) * cap
-            )
-        for u, rec in zip(members, recs):
-            meta = origins[(u.req, u.key)][1]
-            blocked_outs[u.req][u.key] = (rec, meta)
-            host_fetch.append((u.req, u.key, rec, meta))
-    if host_fetch:
-        # int32 blocks come back and are viewed as each leaf's dtype on the
-        # host, which 64-bit dtypes survive
-        fetched = jax.device_get([rec for _, _, rec, _ in host_fetch])
-        for (req, key, _, meta), blocks in zip(host_fetch, fetched):
-            outs[req][key] = ops.from_blocks(blocks, meta)
-    if _sp:
-        if stats is not None:
-            _sp.set(
-                launches=stats.get("launches", 0) - _launch0,
-                fused_slots=stats.get("fused_slots", 0) - _slots0,
-            )
-        else:
-            _sp.set(launches=len(groups))
-        _sp.set(leaves=len(units))
-    _sp.end()
+        # dispatch every group first, keeping results on device; the host
+        # copies happen once at the end as a single batched transfer
+        # (device_get issues the async copies together), not one blocking
+        # sync per leaf
+        host_fetch: List[Tuple[int, str, Any, "ops.BlockMeta"]] = []
+        for (nb, cap), members in groups.items():
+            idx_pad = np.full((len(members), cap), -1, np.int32)
+            blk_pad = np.zeros((len(members), cap, 8, 128), np.int32)
+            for li, u in enumerate(members):
+                at = 0
+                for seg in u.segments:  # chain order: later slots win
+                    idx_pad[li, at : at + seg.n] = seg.idx
+                    blk_pad[li, at : at + seg.n] = seg.blocks
+                    at += seg.n
+            if sp:
+                h2d += idx_pad.nbytes + blk_pad.nbytes
+            if len(members) == 1:
+                u = members[0]
+                ob, meta = origins[(u.req, u.key)]
+                rec = ops.chain_apply(
+                    ob, jnp.asarray(blk_pad[0]), jnp.asarray(idx_pad[0])
+                )
+                recs = [rec]
+            else:
+                stack = jnp.stack([origins[(u.req, u.key)][0] for u in members])
+                recs = ops.chain_apply_batched(
+                    stack, jnp.asarray(blk_pad), jnp.asarray(idx_pad)
+                )
+            if stats is not None:
+                stats["launches"] = stats.get("launches", 0) + 1
+                stats["fused_slots"] = (
+                    stats.get("fused_slots", 0) + len(members) * cap
+                )
+            for u, rec in zip(members, recs):
+                meta = origins[(u.req, u.key)][1]
+                blocked_outs[u.req][u.key] = (rec, meta)
+                host_fetch.append((u.req, u.key, rec, meta))
+        if host_fetch:
+            # int32 blocks come back and are viewed as each leaf's dtype on
+            # the host, which 64-bit dtypes survive
+            fetched = jax.device_get([rec for _, _, rec, _ in host_fetch])
+            for (req, key, _, meta), blocks in zip(host_fetch, fetched):
+                outs[req][key] = ops.from_blocks(blocks, meta)
+            if sp:
+                d2h = sum(b.nbytes for b in fetched)
+        if sp:
+            if stats is not None:
+                sp.set(
+                    launches=stats.get("launches", 0) - launch0,
+                    fused_slots=stats.get("fused_slots", 0) - slots0,
+                )
+            else:
+                sp.set(launches=len(groups))
+            sp.set(leaves=len(units), h2d_bytes=h2d, d2h_bytes=d2h)
     return list(zip(outs, blocked_outs))
 
 

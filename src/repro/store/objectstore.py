@@ -23,6 +23,8 @@ import zlib
 from pathlib import Path
 from typing import Dict, Optional
 
+from ..obs.tracer import span as _span
+
 try:  # optional dependency — stdlib zlib fallback below
     import zstandard
 except ImportError:  # pragma: no cover - exercised via Codec(backend="zlib")
@@ -53,22 +55,25 @@ class Codec:
             self._d = zstandard.ZstdDecompressor()
 
     def compress(self, payload: bytes) -> bytes:
-        if self.backend == "zstd":
-            return self._c.compress(payload)
-        # zstd levels reach 22; zlib tops out at 9
-        return zlib.compress(payload, min(self.level, 9))
+        with _span("objects.compress"):
+            if self.backend == "zstd":
+                return self._c.compress(payload)
+            # zstd levels reach 22; zlib tops out at 9
+            return zlib.compress(payload, min(self.level, 9))
 
     def decompress(self, blob: bytes) -> bytes:
-        # dispatch on frame magic so mixed-backend stores keep working
-        if blob[:4] == _ZSTD_MAGIC:
-            if zstandard is None:
-                raise RuntimeError(
-                    "blob was written with zstd but zstandard is not installed"
-                )
-            if self.backend == "zstd":
-                return self._d.decompress(blob)
-            return zstandard.ZstdDecompressor().decompress(blob)
-        return zlib.decompress(blob)
+        with _span("objects.decompress"):
+            # dispatch on frame magic so mixed-backend stores keep working
+            if blob[:4] == _ZSTD_MAGIC:
+                if zstandard is None:
+                    raise RuntimeError(
+                        "blob was written with zstd but zstandard is not "
+                        "installed"
+                    )
+                if self.backend == "zstd":
+                    return self._d.decompress(blob)
+                return zstandard.ZstdDecompressor().decompress(blob)
+            return zlib.decompress(blob)
 
     def compressed_size(self, payload: bytes) -> int:
         """Bytes this payload would occupy at rest (the measured Δ)."""
@@ -92,24 +97,28 @@ class ObjectStore:
 
     def put(self, payload: bytes) -> tuple[str, int]:
         """Store a blob; returns (key, stored_bytes)."""
-        key = hashlib.sha256(payload).hexdigest()
+        with _span("hash.sha256"):
+            key = hashlib.sha256(payload).hexdigest()
         path = self._path(key)
         if path.exists():
             return key, path.stat().st_size
         path.parent.mkdir(parents=True, exist_ok=True)
         compressed = self.codec.compress(payload)
-        fd, tmp = tempfile.mkstemp(dir=str(path.parent), suffix=".tmp")
-        try:
-            with os.fdopen(fd, "wb") as f:
-                f.write(compressed)
-            os.replace(tmp, path)  # atomic on POSIX
-        finally:
-            if os.path.exists(tmp):
-                os.unlink(tmp)
+        with _span("objects.write"):
+            fd, tmp = tempfile.mkstemp(dir=str(path.parent), suffix=".tmp")
+            try:
+                with os.fdopen(fd, "wb") as f:
+                    f.write(compressed)
+                os.replace(tmp, path)  # atomic on POSIX
+            finally:
+                if os.path.exists(tmp):
+                    os.unlink(tmp)
         return key, len(compressed)
 
     def get(self, key: str) -> bytes:
-        return self.codec.decompress(self._path(key).read_bytes())
+        with _span("objects.read"):
+            blob = self._path(key).read_bytes()
+        return self.codec.decompress(blob)
 
     def exists(self, key: str) -> bool:
         return self._path(key).exists()

@@ -236,7 +236,8 @@ class VersionStore:
             best_obj = full_payload
             best_stats = None
             if parents:
-                base_flat = self._checkout_flat(parents[0])
+                with _span("store.parent", vid=parents[0]):
+                    base_flat = self._checkout_flat(parents[0])
                 delta_payload, stats = encode_delta(base_flat, flat)
                 if len(delta_payload) < len(full_payload):
                     stored_base = parents[0]
@@ -250,6 +251,8 @@ class VersionStore:
                     stored, len(best_obj), best_stats["changed_blocks"]
                 )
             with self._lock:
+                with _span("hash.sha256"):
+                    content_fp = hashlib.sha256(full_payload).hexdigest()
                 self.versions[vid] = VersionMeta(
                     vid=vid,
                     parents=list(parents),
@@ -260,7 +263,7 @@ class VersionStore:
                     object_key=key,
                     stored_bytes=stored,
                     phi=phi,
-                    content_fp=hashlib.sha256(full_payload).hexdigest(),
+                    content_fp=content_fp,
                 )
                 # a commit only *appends* a (vid, stored_base, object_key)
                 # triple: the whole-graph fingerprint rotates (global-mode
@@ -667,35 +670,36 @@ class VersionStore:
             self._save_meta_locked()
 
     def _save_meta_locked(self) -> None:
-        blob = msgpack.packb(
-            {
-                "next_vid": self._next_vid,
-                "versions": {
-                    str(v): dataclasses.asdict(m) for v, m in self.versions.items()
-                },
-                "edge_cache": {
-                    f"{a},{b}": ent for (a, b), ent in self._edge_cache.items()
-                },
-                "refs": {
-                    "branches": {
-                        name: vid for name, vid in self.refs["branches"].items()
+        with _span("store.save_meta"):
+            blob = msgpack.packb(
+                {
+                    "next_vid": self._next_vid,
+                    "versions": {
+                        str(v): dataclasses.asdict(m) for v, m in self.versions.items()
                     },
-                    "tags": {name: vid for name, vid in self.refs["tags"].items()},
-                    "head": self.refs["head"],
+                    "edge_cache": {
+                        f"{a},{b}": ent for (a, b), ent in self._edge_cache.items()
+                    },
+                    "refs": {
+                        "branches": {
+                            name: vid for name, vid in self.refs["branches"].items()
+                        },
+                        "tags": {name: vid for name, vid in self.refs["tags"].items()},
+                        "head": self.refs["head"],
+                    },
+                    "last_repack": self.last_repack,
                 },
-                "last_repack": self.last_repack,
-            },
-            use_bin_type=True,
-        )
-        fd, tmp = tempfile.mkstemp(dir=str(self.root))
-        try:
-            with os.fdopen(fd, "wb") as f:
-                f.write(blob)
-            os.replace(tmp, self._meta_path)
-        finally:
-            if os.path.exists(tmp):
-                os.unlink(tmp)
-        self._unflushed_accesses = 0  # any metadata write persists counts
+                use_bin_type=True,
+            )
+            fd, tmp = tempfile.mkstemp(dir=str(self.root))
+            try:
+                with os.fdopen(fd, "wb") as f:
+                    f.write(blob)
+                os.replace(tmp, self._meta_path)
+            finally:
+                if os.path.exists(tmp):
+                    os.unlink(tmp)
+            self._unflushed_accesses = 0  # any metadata write persists counts
 
     def _load_meta(self) -> None:
         obj = msgpack.unpackb(self._meta_path.read_bytes(), raw=False)

@@ -140,7 +140,8 @@ class TestEndToEndDelta:
         new_t = base_t.at[100:120].add(1.0)  # localized edit
         bb, meta = ops.to_blocks(base_t)
         nb_, _ = ops.to_blocks(new_t)
-        idx, blocks, n = ops.sparse_encode(bb, nb_)
+        mask, n = ops.count_changed(bb, nb_)
+        idx, blocks = ops.compact(mask, nb_, n)
         assert 0 < n < bb.shape[0]
         rec = ops.sparse_apply(bb, blocks, idx)
         out = ops.from_blocks(rec, meta)
@@ -191,7 +192,8 @@ class TestKernelProperties:
     @given(block_pairs())
     def test_sparse_encode_apply_roundtrip(self, pair):
         a, b, rows = pair
-        idx, blocks, n = ops.sparse_encode(a, b)
+        mask, n = ops.count_changed(a, b)
+        idx, blocks = ops.compact(mask, b, n)
         assert n == len(rows)
         rec = ops.sparse_apply(a, blocks, idx)
         np.testing.assert_array_equal(np.asarray(rec), np.asarray(b))

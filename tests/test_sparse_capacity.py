@@ -50,8 +50,10 @@ class TestSparseEncodeCapacity:
         np.testing.assert_array_equal(np.asarray(rec), np.asarray(b))
 
     def test_auto_capacity_unaffected(self):
+        # the commit path's capacity, sized from the exact changed count
         a, b, changed = _pair()
-        idx, blocks, n = ops.sparse_encode(a, b)
+        mask, n = ops.count_changed(a, b)
+        idx, blocks = ops.compact(mask, b, n)
         assert n == changed
         rec = ops.sparse_apply(a, blocks, idx)
         np.testing.assert_array_equal(np.asarray(rec), np.asarray(b))

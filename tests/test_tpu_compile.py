@@ -8,6 +8,7 @@ explicitly: the process's default backend is still the CPU.
 """
 
 import functools
+import re
 import sys
 from pathlib import Path
 
@@ -91,6 +92,28 @@ def test_changed_block_mask(one_chip, nb):
         sharding=one_chip,
     )
     assert "tpu_custom_call" in hlo
+
+
+def test_changed_block_mask_names(one_chip):
+    """The trace reduction keys on these names (``block_diff_roofline``):
+    the program ``jit_changed_block_mask`` and its ``%changed_block_mask``
+    custom call, whose first operand's shape gives the block count."""
+    x = jax.ShapeDtypeStruct((WQ, 8, 128), jnp.int32, sharding=one_chip)
+    hlo = changed_block_mask.lower(x, x, interpret=False).compile().as_text()
+    assert hlo.startswith("HloModule jit_changed_block_mask,")
+    call = re.search(r"%changed_block_mask[.\d]* = \S+ custom-call\((.*)$", hlo, re.M)
+    assert call and 'custom_call_target="tpu_custom_call"' in call.group(1)
+    assert f"s32[{WQ},8,128]" in call.group(1)
+
+
+def test_compact_name(one_chip):
+    """``compact_roofline`` reads the device time of ``jit__compact``."""
+    hlo = ops._compact.lower(
+        jax.ShapeDtypeStruct((EMBED, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((EMBED, 8, 128), jnp.int32, sharding=one_chip),
+        capacity=EMBED_SLOTS,
+    ).compile().as_text()
+    assert hlo.startswith("HloModule jit__compact,")
 
 
 def test_compact(one_chip):
