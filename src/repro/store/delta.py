@@ -59,13 +59,18 @@ BlockedTree = Dict[str, BlockedLeaf]
 _SLOT_BUCKET_MIN = 8
 
 
+def payload_leaves(tree: Any) -> Dict[str, Any]:
+    """A pytree's leaves as given, keyed by '/'-joined path."""
+    return {
+        "/".join(_path_str(p) for p in path): leaf
+        for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]
+    }
+
+
 def flatten_payload(tree: Any) -> FlatTree:
-    """Flatten a pytree to {path: np.ndarray} with '/'-joined keys."""
-    flat = {}
-    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        key = "/".join(_path_str(p) for p in path)
-        flat[key] = np.asarray(leaf)
-    return flat
+    """Flatten a pytree to {path: np.ndarray} with '/'-joined keys.  NumPy
+    leaves come back as the caller's own arrays, not copies."""
+    return {k: np.asarray(leaf) for k, leaf in payload_leaves(tree).items()}
 
 
 def _path_str(p) -> str:

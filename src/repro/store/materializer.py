@@ -33,7 +33,10 @@ parts:
 * :class:`Materializer` — executes plans against the :class:`ObjectStore`,
   feeding every decoded tree (intermediates included — they are exactly the
   hot chain prefixes) through the cache, and exposes hit/decode statistics
-  plus ``prefetch`` for repack-time cache warming.
+  plus ``prefetch`` for repack-time cache warming.  Commits feed the cache
+  too: :meth:`Materializer.keep` writes each committed tree through under
+  its new vid, so the next commit's parent (and a read of the new tip) is a
+  hit rather than a decode from disk.
 
 The cache budget is the ``cache_budget_bytes`` knob on
 :class:`~repro.store.version_store.VersionStore` (default 256 MiB; 0 disables
@@ -58,6 +61,9 @@ from typing import (
     Tuple,
 )
 
+import jax
+import numpy as np
+
 from ..obs.tracer import span as _span
 from .delta import (
     BlockedTree,
@@ -65,6 +71,7 @@ from .delta import (
     apply_delta,
     apply_delta_chains,
     decode_full,
+    payload_leaves,
 )
 
 if TYPE_CHECKING:  # pragma: no cover - import cycle guard (store owns us)
@@ -89,6 +96,22 @@ def _freeze(flat: FlatTree) -> FlatTree:
     for arr in flat.values():
         arr.flags.writeable = False
     return flat
+
+
+def _owned(leaf: Any, arr: np.ndarray) -> np.ndarray:
+    """``arr`` (``np.asarray(leaf)``) if no one but the cache can write its
+    memory and it is C-contiguous, else a C-contiguous copy.  The caller's
+    own NumPy leaf, or a view of it, is copied; so is a view of memory some
+    other object owns (a tensor exposing its buffer).  A ``jax.Array``'s
+    host value is immutable and is kept as it is."""
+    if isinstance(leaf, np.ndarray):
+        # bounds overlap: conservative, and O(1) where an exact test is not
+        private = not np.may_share_memory(arr, leaf)
+    else:
+        private = isinstance(leaf, jax.Array) or arr.flags.owndata
+    if private and arr.flags.c_contiguous:
+        return arr
+    return np.array(arr, order="C")
 
 
 def storage_fingerprint(versions: Dict[int, Any]) -> str:
@@ -202,10 +225,12 @@ class MaterializationCache:
       adopts one whole-graph fingerprint at a time and the first operation
       under a new one drops every entry.
 
-    Entries are evicted least-recently-used once resident bytes exceed
-    ``budget_bytes``; a tree larger than the whole budget is simply not
-    cached.  All access goes through one internal lock — the service tier's
-    reader threads share this cache concurrently.
+    Entries come from checkouts (every decoded tree) and from commits (the
+    committed tree, through :meth:`Materializer.keep`).  They are evicted
+    least-recently-used once resident bytes exceed ``budget_bytes``; a tree
+    larger than the whole budget is simply not cached.  All access goes
+    through one internal lock — the service tier's reader threads share
+    this cache concurrently.
     """
 
     def __init__(self, budget_bytes: int) -> None:
@@ -366,6 +391,9 @@ class Materializer:
     unchanged: a warm cache sees exactly the trees it would have seen
     stepwise.  ``fuse_chains=False`` keeps the legacy one-hop-at-a-time
     path; both are bit-identical.
+
+    The store's commit path feeds the cache as well (:meth:`keep`): the tree
+    just committed is what the next commit on the branch diffs against.
     """
 
     def __init__(
@@ -491,6 +519,28 @@ class Materializer:
             self._execute(plan)
             warmed += 1
         return warmed
+
+    def keep(self, vid: int, flat: FlatTree, payload: Any) -> bool:
+        """Cache ``flat``, the tree just committed as ``vid``; returns
+        whether it was kept.
+
+        Call only once the commit is durable.  The entry is what a checkout
+        of ``vid`` would cache: C-contiguous, read-only, keys sorted as
+        ``decode_full`` returns them, tagged with ``vid``'s entry
+        fingerprint.  A tree the budget cannot hold costs nothing.  Under
+        the global discipline the cache first adopts the post-commit epoch
+        (the next checkout would purge to it anyway), so the entry survives
+        until the next commit.
+        """
+        cache = self.cache
+        if cache.budget_bytes <= 0 or tree_nbytes(flat) > cache.budget_bytes:
+            return False
+        leaves = payload_leaves(payload)
+        kept = {k: _owned(leaves[k], flat[k]) for k in sorted(flat)}
+        if self.invalidation == "global":
+            cache.ensure_fingerprint(self._store.storage_fingerprint())
+        cache.put(vid, _freeze(kept), self._entry_fp(vid))
+        return True
 
     def stats(self) -> Dict[str, int]:
         with self._stats_lock:

@@ -28,7 +28,10 @@ appends triples but rewrites none) keeps every warm entry alive and
 interleaved save+serve traffic never goes cold; ``repack`` rewrites chains
 and purges the cache wholesale.  ``cache_invalidation="global"`` keeps the
 legacy whole-graph fingerprint that any commit rotates (purging everything);
-either way a stale tree can never be served.  ``checkout`` serves hot
+either way a stale tree can never be served.  Each commit writes its tree
+through into the cache under the new vid (once it is durable, and only if
+it fits the budget), so the next commit's parent is a hit, not a decode
+from disk.  ``checkout`` serves hot
 versions from memory; ``checkout_many`` batches k checkouts into one plan,
 bit-identical to k sequential calls but strictly cheaper on chain-sharing
 batches.  The cache budget is the ``cache_budget_bytes`` constructor knob
@@ -273,6 +276,12 @@ class VersionStore:
                 if update_branch is not None:
                     self.refs["branches"][update_branch] = vid
                 self._save_meta()
+            # the committed tree is the next commit's parent: cache it now,
+            # off the store lock, so that commit does not decode it from disk
+            with _span("store.keep") as ksp:
+                kept = self.materializer.keep(vid, flat, payload)
+                if ksp:
+                    ksp.set(kept=int(kept))
             if csp:
                 csp.set(
                     vid=vid,

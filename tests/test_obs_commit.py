@@ -23,7 +23,7 @@ from repro.store.version_store import VersionStore
 
 COMMIT_CHILDREN = {"delta.encode_full", "store.parent", "delta.encode_delta",
                    "hash.sha256", "objects.compress", "objects.write",
-                   "store.save_meta"}
+                   "store.save_meta", "store.keep"}
 DIFF_CHILDREN = {"delta.upload", "delta.count", "delta.fetch", "delta.pack"}
 
 
@@ -105,6 +105,8 @@ def test_commit_span_tree_and_byte_counters(tmp_path, monkeypatch):
     assert all(ancestors(s)[-1:] == ["store.commit"] for s in spans if s is not commit)
     direct = {s.name for s in spans if s.parent_id == commit.span_id}
     assert direct == COMMIT_CHILDREN
+    (keep,) = [s for s in spans if s.name == "store.keep"]
+    assert keep.attrs["kept"] == 0  # a zero budget caches nothing
     assert sum(s.name == "hash.sha256" for s in spans) == 2  # key and fingerprint
     (diff,) = [s for s in spans if s.name == "delta.encode_delta"]
     assert {s.name for s in spans if s.parent_id == diff.span_id} == DIFF_CHILDREN

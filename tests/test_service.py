@@ -47,6 +47,9 @@ def build_repo(tmp_path, versions=6):
 class TestCoalescing:
     def test_concurrent_same_ref_single_materialization(self, tmp_path):
         repo, trees = build_repo(tmp_path)
+        # a commit caches its own tree: reopen so the tip starts cold
+        repo.close()
+        repo = Repository(tmp_path)
         tip = repo.resolve("main")
 
         async def go():
