@@ -1,33 +1,45 @@
-"""Tiny cells for the CPU rehearsal: each real cell with its configuration
-cut to a size the interpreted kernels get through in a second or two."""
+"""Tiny cells for the CPU rehearsal: each cell of ``BENCHMARK.json`` with its
+configuration cut to a size the interpreted kernels get through in a second
+or two.  The cut is the configuration's own file,
+``bench/configs/<config>.tiny.json``, whose keys replace the configuration's;
+nothing here names a configuration or a cell."""
 
 from __future__ import annotations
 
-import copy
+import json
 
 import pytest
 
 from bench import harness
 
-TINY_CONFIG = {
-    "ckpt-minicpm-2b-fsdp8": {
-        "hidden_size": 128, "intermediate_size": 256, "num_attention_heads": 2,
-        "num_key_value_heads": 2, "head_dim": 64, "vocab_size": 512,
-        "num_hidden_layers": 2, "history": {"saves": 1},
-        "store": {"cache_budget_bytes": 300_000, "codec": "zstd"},
-    },
-}
 TINY_TRAFFIC = {"warm_seconds": 0.3}
 
+_BM = harness.benchmark()
 #: each cell of BENCHMARK.json: configuration and traffic mix
-CELLS = {w["name"]: (w["config"], w["traffic"]) for w in harness.benchmark()["workloads"]}
-#: the end-to-end metric each cell reports besides ``setup_s``
-HEADLINE = {"ckpt-save": "commit_MBps"}
+CELLS = {w["name"]: (w["config"], w["traffic"]) for w in _BM["workloads"]}
+#: each configuration of BENCHMARK.json
+CONFIGS = [c["name"] for c in _BM["configs"]]
+
+
+def tiny_config(config: str) -> dict:
+    """Configuration ``config`` with the keys of its ``.tiny.json`` in place."""
+    path = harness.HERE / "configs" / f"{config}.tiny.json"
+    if not path.exists():
+        raise FileNotFoundError(
+            f"{path} not found: every configuration brings its CPU-rehearsal sizes")
+    full = json.loads((harness.HERE / "configs" / f"{config}.json").read_text())
+    return {**full, **json.loads(path.read_text())}
+
+
+def reference(config: str):
+    """The plain reference module of ``config``."""
+    return harness._module(harness.HERE / "configs" / f"{config}.py")
 
 
 def tiny_cell(name: str) -> harness.Cell:
-    cell = harness.Cell.build(name, *CELLS[name])
-    cell.config = {**copy.deepcopy(cell.config), **TINY_CONFIG[cell.config["name"]]}
+    config, traffic = CELLS[name]
+    cell = harness.Cell.build(name, config, traffic)
+    cell.config = tiny_config(config)
     cell.traffic = {**cell.traffic, **TINY_TRAFFIC}
     return cell
 

@@ -1,8 +1,9 @@
-"""The comparison that decides ``correct`` catches what it must: the
-control of each configuration, and faults planted in the timed path while
-the rest of a run goes on as usual (at a tiny size, on the CPU): a save that
-leaves the state unchanged, half of a save left out, a byte altered where
-the tree is taken in, and a save acknowledged but never written."""
+"""The comparison that decides ``correct`` catches what it must, in every
+cell: the control of the cell's configuration, and faults planted in the
+timed path while the rest of a run goes on as usual (at a tiny size, on the
+CPU): a save that leaves the state unchanged, half of a save left out, a
+byte altered where the tree is taken in, and a save acknowledged but never
+written."""
 
 from __future__ import annotations
 
@@ -12,6 +13,7 @@ import numpy as np
 import pytest
 
 from bench import harness, verify
+from bench.conftest import CELLS
 
 PEAKS = {"hbm_bytes_per_s": 819e9}
 
@@ -21,8 +23,9 @@ def run(cell, tmp_path, **kw):
     return out.numbers
 
 
-def test_bench_control_is_not_correct(tiny, tmp_path):
-    numbers = run(tiny("ckpt-save"), tmp_path, control=True)
+@pytest.mark.parametrize("name", CELLS)
+def test_bench_control_is_not_correct(name, tiny, tmp_path):
+    numbers = run(tiny(name), tmp_path, control=True)
     assert not verify.passed(numbers), numbers
     assert numbers["commits_lost"] > 0
 
@@ -69,15 +72,18 @@ FAULTS = {"state_unchanged": _unchanged, "half_left_out": _half_left_out,
           "flipped_byte": _flipped_byte}
 
 
+@pytest.mark.parametrize("name", CELLS)
 @pytest.mark.parametrize("fault", FAULTS)
-def test_bench_planted_fault_is_not_correct(fault, tiny, tmp_path, monkeypatch):
+def test_bench_planted_fault_is_not_correct(fault, name, tiny, tmp_path, monkeypatch):
     FAULTS[fault](monkeypatch)
-    numbers = run(tiny("ckpt-save"), tmp_path)
+    numbers = run(tiny(name), tmp_path)
     assert not verify.passed(numbers), numbers
     assert numbers["commits_lost"] > 0
 
 
-def test_bench_commit_acknowledged_but_not_stored_is_not_correct(tiny, tmp_path, monkeypatch):
+@pytest.mark.parametrize("name", CELLS)
+def test_bench_commit_acknowledged_but_not_stored_is_not_correct(name, tiny, tmp_path,
+                                                                 monkeypatch):
     """Saves acknowledged while their objects sit in a write buffer that is
     never flushed: the store that wrote them reads them, a reopened one
     cannot."""
@@ -98,5 +104,5 @@ def test_bench_commit_acknowledged_but_not_stored_is_not_correct(tiny, tmp_path,
 
     monkeypatch.setattr(ObjectStore, "put", buffered_put)
     monkeypatch.setattr(ObjectStore, "get", get)
-    numbers = run(tiny("ckpt-save"), tmp_path)
+    numbers = run(tiny(name), tmp_path)
     assert numbers["commits_lost"] > 0
