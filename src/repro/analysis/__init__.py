@@ -41,8 +41,9 @@ Auditor (``python -m repro.analysis audit``):
     Batch: accumulate device results, one ``jax.device_get`` after the loop.
 ``audit.shape-bucket`` (ERROR)
     Two sub-checks: the bucket functions (``_slot_bucket``,
-    ``_round_capacity``, ``_bucket_rows``, ``_bucket_width``) must cover,
-    quantize (pow2 / multiple-of-8), and be idempotent + monotone; and
+    ``_round_capacity``, ``grown_blocks``, ``_bucket_rows``,
+    ``_bucket_width``) must cover, quantize (pow2 / multiple-of-8 / under an
+    eighth of padding), and be idempotent + monotone; and
     same-bucket sizes must trace to identical Pallas kernel shapes —
     otherwise jit/kernel compile caches fragment per size.
 ``audit.io-alias`` (WARNING)

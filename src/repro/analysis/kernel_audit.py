@@ -401,7 +401,7 @@ class BucketContract:
 
     name: str
     fn: Callable[[int], int]
-    kind: str                  # "pow2" | "mult8"
+    kind: str                  # "pow2" | "mult8" | "eighth"
     max_check: int = 4096
 
 
@@ -414,6 +414,7 @@ def bucket_contracts() -> List[BucketContract]:
         BucketContract("store.delta._slot_bucket", delta._slot_bucket, "pow2"),
         BucketContract("kernels.ops._round_capacity", ops._round_capacity,
                        "pow2"),
+        BucketContract("kernels.ops.grown_blocks", ops.grown_blocks, "eighth"),
         BucketContract("core.solvers.jax_backend._bucket_rows",
                        jb._bucket_rows, "pow2"),
         BucketContract("core.solvers.jax_backend._bucket_width",
@@ -422,9 +423,10 @@ def bucket_contracts() -> List[BucketContract]:
 
 
 def check_bucket_contract(report: Report, c: BucketContract) -> None:
-    """Bucket functions must cover (f(k) >= k), quantize (pow2 / mult-of-8),
-    be idempotent (f(f(k)) == f(k)) and monotone — the conditions under which
-    jit caches are shared and recompiles stay O(log max_size)."""
+    """Bucket functions must cover (f(k) >= k), quantize (pow2 / mult-of-8 /
+    under an eighth of padding), be idempotent (f(f(k)) == f(k)) and
+    monotone — the conditions under which jit caches are shared and
+    recompiles stay O(log max_size)."""
     report.bump("audit.shape-bucket")
     problems: List[str] = []
     prev = 0
@@ -436,6 +438,8 @@ def check_bucket_contract(report: Report, c: BucketContract) -> None:
             problems.append(f"f({k})={b} not a power of two")
         if c.kind == "mult8" and b % 8:
             problems.append(f"f({k})={b} not a multiple of 8")
+        if c.kind == "eighth" and 8 * (b - k) >= k:
+            problems.append(f"f({k})={b} pads by an eighth or more")
         if c.fn(b) != b:
             problems.append(f"f(f({k}))={c.fn(b)} != f({k})={b} (not "
                             f"idempotent)")

@@ -6,7 +6,8 @@ operations the store uses:
 
 * :func:`to_blocks` / :func:`from_blocks` — byte-preserving (bitcast + pad)
   layout conversion, on the host for NumPy input and on the device for JAX
-  input;
+  input; :func:`grown_blocks` sizes the padded block count of a leaf that
+  grows between versions;
 * :func:`xor_encode` / :func:`xor_apply` — the paper's XOR delta variant;
 * :func:`count_changed` / :func:`compact` / :func:`sparse_apply` —
   block-sparse delta: changed-block mask (Pallas) and its count,
@@ -23,7 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,21 +47,38 @@ class BlockMeta:
     num_blocks: int
 
 
-def to_blocks(x) -> Tuple[jnp.ndarray, BlockMeta]:
+def num_blocks_of(nbytes: int) -> int:
+    """Blocks that hold ``nbytes`` bytes."""
+    return -(-nbytes // BLOCK_BYTES)
+
+
+def grown_blocks(num_blocks: int) -> int:
+    """The block count a leaf of ``num_blocks`` blocks is padded to where it
+    grew since its parent: rounded up to its four leading bits, so the
+    padding is under an eighth of the leaf and a leaf that grows a little at
+    every version keeps one padded size (one compiled program per kernel)
+    for many versions.  Counts under 16 are their own bucket."""
+    shift = max(0, num_blocks.bit_length() - 4)
+    return -(-num_blocks >> shift) << shift
+
+
+def to_blocks(x, num_blocks: Optional[int] = None) -> Tuple[jnp.ndarray, BlockMeta]:
     """View a tensor's bytes as (num_blocks, 8, 128) int32, zero-padded.
 
+    ``num_blocks`` pads to more blocks than the bytes fill (a grown leaf's
+    bucket, :func:`grown_blocks`); :func:`from_blocks` drops the padding.
     A NumPy array is viewed on the host and uploaded as int32 blocks, so
     every dtype keeps every byte: with ``jax_enable_x64`` off (the default),
     uploading a float64/int64 array first would narrow it to 32 bits.  A JAX
     array is bitcast on the device."""
     if isinstance(x, np.ndarray):
-        return _host_to_blocks(x)
+        return _host_to_blocks(x, num_blocks)
     nbytes = x.size * x.dtype.itemsize
+    num_blocks = num_blocks or num_blocks_of(nbytes)
     flat_u8 = jax.lax.bitcast_convert_type(x.reshape(-1), jnp.uint8).reshape(-1)
-    pad = (-nbytes) % BLOCK_BYTES
+    pad = num_blocks * BLOCK_BYTES - nbytes
     if pad:
         flat_u8 = jnp.concatenate([flat_u8, jnp.zeros((pad,), jnp.uint8)])
-    num_blocks = (nbytes + pad) // BLOCK_BYTES
     as_i32 = jax.lax.bitcast_convert_type(
         flat_u8.reshape(-1, 4), jnp.int32
     ).reshape(num_blocks, 8, 128)
@@ -68,8 +86,9 @@ def to_blocks(x) -> Tuple[jnp.ndarray, BlockMeta]:
     return as_i32, meta
 
 
-def _host_to_blocks(x: np.ndarray) -> Tuple[jnp.ndarray, BlockMeta]:
-    num_blocks = -(-x.nbytes // BLOCK_BYTES)
+def _host_to_blocks(x: np.ndarray,
+                    num_blocks: Optional[int]) -> Tuple[jnp.ndarray, BlockMeta]:
+    num_blocks = num_blocks or num_blocks_of(x.nbytes)
     raw = np.ascontiguousarray(x).reshape(-1).view(np.uint8)
     pad = num_blocks * BLOCK_BYTES - x.nbytes
     if pad:
@@ -167,12 +186,24 @@ def count_changed(
     return mask, int(jnp.sum(mask[:, 0]))
 
 
+def compact_capacity(n: int, num_blocks: int) -> int:
+    """The capacity :func:`compact` packs ``n`` changed rows of a
+    ``num_blocks``-row mask into: a power of two, and at least 1/32 of the
+    rows.  A leaf whose changed count wanders from version to version (a
+    table's update batches) then keeps one capacity, and one ``_compact``
+    program, while the count stays under ~3% of its blocks; a leaf meets at
+    most six capacities."""
+    return _round_capacity(max(1, n, num_blocks // 32))
+
+
 def compact(
     mask: jnp.ndarray, new_blocks: jnp.ndarray, n: int
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """``(idx, blocks)`` of the ``n`` changed rows, in a capacity rounded up
-    to a power of two (padded past ``n``; see :func:`_compact`)."""
-    idx, blocks, _ = _compact(mask, new_blocks, _round_capacity(max(1, n)))
+    """``(idx, blocks)`` of the ``n`` changed rows, in
+    :func:`compact_capacity` slots (padded past ``n``; see
+    :func:`_compact`)."""
+    capacity = compact_capacity(n, mask.shape[0])
+    idx, blocks, _ = _compact(mask, new_blocks, capacity)
     return idx, blocks
 
 

@@ -6,7 +6,12 @@ state, dataset shards) either fully or as a delta against a base payload:
 * leaves present in both with identical shape/dtype → **block-sparse delta**
   (changed-block indices + packed 4 KiB blocks, from the Pallas mask/compact
   path) — the common case for checkpoint chains where few blocks move;
-* new / reshaped leaves → stored whole;
+* leaves whose leading axis *grew* (same trailing shape and dtype: a table
+  column that rows were appended to) → the same block-sparse delta against
+  the parent zero-padded to the new size, so the appended blocks are
+  changed blocks past the parent's end; the wire entry carries the new
+  shape;
+* new / shrunk / otherwise reshaped leaves → stored whole;
 * deleted leaves → tombstones.
 
 Wire format is msgpack; zstd happens in the object store.  The codec also
@@ -33,12 +38,17 @@ into one kernel launch, so jit caches are shared across chains of different
 lengths and sparsity — the same shape-bucketing discipline as
 ``core/solvers/jax_backend.py``.  A leaf's chain segment restarts at any
 mid-chain full rewrite (shape/dtype change) and ends at a tombstone; only
-the segments between those events reach the kernel.
+the segments between those events reach the kernel.  A leaf that grows
+along its chain is rebuilt at the last shape of its chain: the origin is
+zero-padded to that size (:func:`repro.kernels.ops.grown_blocks`, so a
+leaf growing a little at every version keeps one padded block count) and
+every step's blocks land inside it.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, FrozenSet, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -116,8 +126,19 @@ def decode_full(payload: bytes) -> FlatTree:
         return {k: _arr_from_wire(v) for k, v in obj["leaves"].items()}
 
 
+def _grew(base: np.ndarray, new: np.ndarray) -> bool:
+    """``new`` is ``base`` with rows appended: same dtype and trailing
+    shape, a longer leading axis."""
+    return (base.ndim >= 1 and base.ndim == new.ndim and base.dtype == new.dtype
+            and base.shape[1:] == new.shape[1:] and base.shape[0] < new.shape[0])
+
+
 def encode_delta(base: FlatTree, new: FlatTree) -> Tuple[bytes, Dict]:
     """Delta payload turning `base` into `new`, plus stats for Φ modelling.
+
+    A leaf whose leading axis grew is diffed against the parent zero-padded
+    to the new size (both in :func:`repro.kernels.ops.grown_blocks` blocks),
+    and its wire entry carries the new ``shape``.
 
     Traced as ``delta.encode_delta``; for each leaf diffed on the device,
     ``delta.upload`` (host staging and the *enqueue* of both leaves'
@@ -128,49 +149,65 @@ def encode_delta(base: FlatTree, new: FlatTree) -> Tuple[bytes, Dict]:
     ``delta.pack``.  The span's attributes count what crossed between host
     and device, at the transfer sites: ``h2d_bytes`` (the blocks uploaded
     from host leaves), ``d2h_bytes`` (each int32 changed count and the
-    fetched ``idx[:n]`` and ``blocks[:n]``) and ``changed_blocks``.
+    fetched ``idx[:n]`` and ``blocks[:n]``, a grown leaf's whole compacted
+    capacity); and the leaves and blocks:
+    ``changed_blocks``, ``total_blocks`` (blocks diffed, padding
+    excluded), ``grown_leaves`` (diffed after growing) and ``full_leaves``
+    (stored whole).
     """
     with _span("delta.encode_delta") as sp:
         sparse, full = {}, {}
-        stats = {"changed_blocks": 0, "total_blocks": 0, "full_leaves": 0}
+        stats = {"changed_blocks": 0, "total_blocks": 0, "full_leaves": 0,
+                 "grown_leaves": 0}
         h2d = d2h = 0
         tombstones = [k for k in base if k not in new]
         for key, arr in new.items():
             b = base.get(key)
-            if b is None or b.shape != arr.shape or b.dtype != arr.dtype:
+            grown = b is not None and _grew(b, arr)
+            if b is None or (not grown and (b.shape != arr.shape
+                                            or b.dtype != arr.dtype)):
                 full[key] = _arr_to_wire(arr)
                 stats["full_leaves"] += 1
                 continue
+            real = ops.num_blocks_of(arr.nbytes)
+            padded = (ops.grown_blocks(real),) if grown else ()
             # NumPy in: the byte view happens on the host, so 64-bit leaves
             # keep every byte (ops.to_blocks)
             with _span("delta.upload"):
-                bb, _ = ops.to_blocks(b)
-                nb, _ = ops.to_blocks(arr)
+                bb, _ = ops.to_blocks(b, *padded)
+                nb, _ = ops.to_blocks(arr, *padded)
             with _span("delta.count"):
                 mask, n = ops.count_changed(bb, nb)
             stats["changed_blocks"] += n
-            stats["total_blocks"] += int(bb.shape[0])
+            stats["total_blocks"] += real
+            stats["grown_leaves"] += grown
             if sp:
                 # a device leaf is blocked on the device: nothing crosses
                 h2d += sum(blk.nbytes for blk, x in ((bb, b), (nb, arr))
                            if isinstance(x, np.ndarray))
                 d2h += 4  # the int32 count
+            entry = {"idx": b"", "blocks": b"", "n": int(n)}
+            if grown:
+                entry["shape"] = list(arr.shape)
+            sparse[key] = entry
             if n == 0:
-                sparse[key] = {"idx": b"", "blocks": b"", "n": 0}
                 continue
             with _span("delta.fetch"):
                 idx, blocks = ops.compact(mask, nb, n)
+                if grown:
+                    # a grown leaf's changed count differs from version to
+                    # version, and a device slice builds a program per
+                    # length: the whole capacity comes down instead
+                    idx, blocks = jax.device_get((idx, blocks))
+                    fetched = idx.nbytes + blocks.nbytes
                 # trim padding before serialization (padding is a
                 # device-side artifact)
                 host_idx = np.asarray(idx[:n], np.int32)
                 host_blocks = np.asarray(blocks[:n], np.int32)
-                sparse[key] = {
-                    "idx": host_idx.tobytes(),
-                    "blocks": host_blocks.tobytes(),
-                    "n": int(n),
-                }
+                entry["idx"] = host_idx.tobytes()
+                entry["blocks"] = host_blocks.tobytes()
             if sp:
-                d2h += host_idx.nbytes + host_blocks.nbytes
+                d2h += fetched if grown else host_idx.nbytes + host_blocks.nbytes
         with _span("delta.pack"):
             payload = msgpack.packb(
                 {"kind": "delta", "sparse": sparse, "full": full,
@@ -178,8 +215,7 @@ def encode_delta(base: FlatTree, new: FlatTree) -> Tuple[bytes, Dict]:
                 use_bin_type=True,
             )
         if sp:
-            sp.set(h2d_bytes=h2d, d2h_bytes=d2h,
-                   changed_blocks=stats["changed_blocks"])
+            sp.set(h2d_bytes=h2d, d2h_bytes=d2h, **stats)
     return payload, stats
 
 
@@ -191,6 +227,9 @@ class SparseLeafDelta:
     idx: np.ndarray      # (n,) int32 changed block rows
     blocks: np.ndarray   # (n, 8, 128) int32 packed new block content
     n: int
+    # the leaf's new shape where its leading axis grew (None: unchanged);
+    # payloads written before leaves could grow carry none
+    shape: Optional[Tuple[int, ...]] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -210,23 +249,46 @@ def decode_delta_wire(payload: bytes) -> DeltaWire:
     sparse: Dict[str, SparseLeafDelta] = {}
     for key, d in obj["sparse"].items():
         n = int(d["n"])
+        shape = tuple(d["shape"]) if "shape" in d else None
         if n == 0:
             sparse[key] = SparseLeafDelta(
-                np.empty((0,), np.int32), np.empty((0, 8, 128), np.int32), 0
+                np.empty((0,), np.int32), np.empty((0, 8, 128), np.int32), 0,
+                shape,
             )
             continue
         idx = np.frombuffer(d["idx"], np.int32)
         blocks = np.frombuffer(d["blocks"], np.int32).reshape(-1, 8, 128)
-        sparse[key] = SparseLeafDelta(idx, blocks, n)
+        sparse[key] = SparseLeafDelta(idx, blocks, n, shape)
     return DeltaWire(sparse, obj["full"], frozenset(obj["tombstones"]))
+
+
+def _grown_meta(arr: np.ndarray, shape: Tuple[int, ...]) -> "ops.BlockMeta":
+    """Layout of ``arr`` grown to ``shape``, in its padded block count;
+    raises where ``shape`` is no growth of ``arr`` (a corrupt chain)."""
+    if arr.ndim == 0 or shape[1:] != arr.shape[1:] or shape[0] < arr.shape[0]:
+        raise ValueError(
+            f"corrupt delta: a leaf of shape {arr.shape} cannot grow to {shape}"
+        )
+    nbytes = math.prod(shape) * arr.dtype.itemsize
+    return ops.BlockMeta(str(arr.dtype), tuple(shape), nbytes,
+                         ops.grown_blocks(ops.num_blocks_of(nbytes)))
+
+
+def _zero_grown(arr: np.ndarray, shape: Tuple[int, ...]) -> np.ndarray:
+    """``arr`` with zero rows appended up to ``shape`` (a grown leaf whose
+    delta changed no block: the appended rows are all zero bytes)."""
+    out = np.zeros(shape, arr.dtype)
+    out[: arr.shape[0]] = arr
+    return out
 
 
 def apply_delta(base: FlatTree, payload: Union[bytes, DeltaWire]) -> FlatTree:
     """Stepwise (one-hop) delta application — the reference recreation path.
 
     Unchanged leaves pass through by reference; each changed leaf pays one
-    ``to_blocks``/``sparse_apply``/``from_blocks`` round trip.  Chains should
-    use :func:`apply_delta_chain`, which is bit-identical and fused.
+    ``to_blocks``/``sparse_apply``/``from_blocks`` round trip, a grown leaf
+    in its padded block count.  Chains should use :func:`apply_delta_chain`,
+    which is bit-identical and fused.
     """
     wire = decode_delta_wire(payload) if isinstance(payload, bytes) else payload
     out: FlatTree = {}
@@ -238,12 +300,13 @@ def apply_delta(base: FlatTree, payload: Union[bytes, DeltaWire]) -> FlatTree:
         if key in wire.tombstones:
             continue
         d = wire.sparse.get(key)
+        meta = None if d is None or d.shape is None else _grown_meta(arr, d.shape)
         if d is None or d.n == 0:
-            out[key] = arr
+            out[key] = arr if meta is None else _zero_grown(arr, d.shape)
             continue
-        bb, meta = ops.to_blocks(arr)
+        bb, same = ops.to_blocks(arr, meta and meta.num_blocks)
         rec = ops.sparse_apply(bb, jnp.asarray(d.blocks), jnp.asarray(d.idx))
-        pending[key] = (rec, meta)
+        pending[key] = (rec, meta or same)
     if pending:
         # blocks come back as int32 and are viewed as the leaf's dtype on the
         # host, which 64-bit dtypes survive
@@ -274,26 +337,31 @@ class _LeafProgram:
     key: str
     origin_step: Optional[int]     # None → base tree; else wires[i].full
     segments: List[SparseLeafDelta]
+    shape: Optional[Tuple[int, ...]]  # the last grown shape, if it grew
+
+
+# (origin_step, segments, shape) of one leaf of a chain's final tree
+_Program = Tuple[Optional[int], List[SparseLeafDelta], Optional[Tuple[int, ...]]]
 
 
 def _resolve_leaf_programs(
     base: FlatTree, wires: Sequence[DeltaWire]
-) -> Dict[str, Tuple[Optional[int], List[SparseLeafDelta]]]:
+) -> Dict[str, _Program]:
     """Fold a chain's per-step leaf events into one program per final leaf.
 
     Walks steps in order: tombstones kill a leaf, full rewrites restart its
     chain segment (later sparse deltas apply on the rewritten value), sparse
-    deltas append to the current segment.  The result maps every leaf of the
-    chain's *final* tree to ``(origin_step, segments)``.
+    deltas append to the current segment, and a grown leaf's delta sets the
+    shape the leaf ends at.  The result maps every leaf of the chain's
+    *final* tree to ``(origin_step, segments, shape)``, ``shape`` None where
+    the leaf kept its origin's shape.
     """
-    state: Dict[str, Tuple[Optional[int], List[SparseLeafDelta]]] = {
-        k: (None, []) for k in base
-    }
+    state: Dict[str, _Program] = {k: (None, [], None) for k in base}
     for i, w in enumerate(wires):
         for k in w.tombstones:
             state.pop(k, None)
         for k, d in w.sparse.items():
-            if d.n == 0:
+            if d.n == 0 and d.shape is None:
                 continue
             st = state.get(k)
             if st is None:
@@ -301,14 +369,13 @@ def _resolve_leaf_programs(
                     f"corrupt chain: step {i} carries a sparse delta for "
                     f"leaf {k!r} absent from the running tree"
                 )
-            st[1].append(d)
+            if d.n:
+                st[1].append(d)
+            if d.shape is not None:
+                state[k] = (st[0], st[1], d.shape)
         for k in w.full:
-            state[k] = (i, [])
+            state[k] = (i, [], None)
     return state
-
-
-def _num_blocks(arr: np.ndarray) -> int:
-    return -(-arr.nbytes // BLOCK_BYTES)
 
 
 def apply_delta_chains(
@@ -325,21 +392,24 @@ def apply_delta_chains(
     leaves so repeatedly-edited leaves skip re-``to_blocks``.  Leaves are
     grouped by ``(num_blocks, slot_bucket)`` across *all* requests and each
     group runs as one :func:`repro.kernels.ops.chain_apply_batched` launch.
+    A leaf that grew along its chain runs in the padded block count of its
+    last shape, its origin zero-padded to it.
 
     Returns ``[(tree, blocked)]`` per request, bit-identical to folding
     :func:`apply_delta` over each chain.  ``stats`` (optional) is bumped
     with ``launches`` / ``fused_slots`` for observability.
 
     Traced as ``delta.apply_chains``, with ``h2d_bytes`` (origin leaves
-    uploaded to block form, and the padded slot stacks) and ``d2h_bytes``
-    (the fetched result blocks) besides the launch counts.
+    uploaded to block form, and the padded slot stacks), ``d2h_bytes``
+    (the fetched result blocks) and ``grown_leaves`` (leaves rebuilt at a
+    grown shape) besides the launch counts.
     """
     with _span("delta.apply_chains", requests=len(requests)) as sp:
         # the caller's stats dict accumulates across calls; snapshot so the
         # span attributes only this call's launches
         launch0 = (stats or {}).get("launches", 0)
         slots0 = (stats or {}).get("fused_slots", 0)
-        h2d = d2h = 0
+        h2d = d2h = grown = 0
         wire_chains: List[List[DeltaWire]] = []
         outs: List[FlatTree] = []
         blocked_outs: List[BlockedTree] = []
@@ -353,19 +423,21 @@ def apply_delta_chains(
             out: FlatTree = {}
             outs.append(out)
             blocked_outs.append({})
-            for key, (origin_step, segs) in _resolve_leaf_programs(
+            for key, (origin_step, segs, shape) in _resolve_leaf_programs(
                 base, wires
             ).items():
+                grown += shape is not None
                 if not segs:
                     # untouched leaf (reference passthrough) or plain full
-                    # decode
-                    out[key] = (
+                    # decode, zero-padded where it grew with no block changed
+                    arr = (
                         base[key]
                         if origin_step is None
                         else _arr_from_wire(wires[origin_step].full[key])
                     )
+                    out[key] = arr if shape is None else _zero_grown(arr, shape)
                     continue
-                units.append(_LeafProgram(ri, key, origin_step, segs))
+                units.append(_LeafProgram(ri, key, origin_step, segs, shape))
 
         # shape-bucketed grouping: one fused launch per (num_blocks,
         # slot_bucket)
@@ -373,25 +445,21 @@ def apply_delta_chains(
         origins: Dict[Tuple[int, str], Tuple[Any, "ops.BlockMeta"]] = {}
         for u in units:
             base, _, base_blocked = requests[u.req]
-            if u.origin_step is None:
-                pre = (base_blocked or {}).get(u.key)
-                if pre is not None:
-                    origin_blocks, meta = pre
-                else:
-                    origin_blocks, meta = ops.to_blocks(base[u.key])
-                    if sp and isinstance(base[u.key], np.ndarray):
-                        h2d += origin_blocks.nbytes
+            arr = (base[u.key] if u.origin_step is None else _arr_from_wire(
+                wire_chains[u.req][u.origin_step].full[u.key]))
+            final = None if u.shape is None else _grown_meta(arr, u.shape)
+            rows = final and final.num_blocks
+            pre = (base_blocked or {}).get(u.key) if u.origin_step is None else None
+            if pre is not None and pre[0].shape[0] == (rows or pre[1].num_blocks):
+                origin_blocks, meta = pre
             else:
-                arr = _arr_from_wire(
-                    wire_chains[u.req][u.origin_step].full[u.key]
-                )
-                origin_blocks, meta = ops.to_blocks(arr)
-                if sp:
+                origin_blocks, meta = ops.to_blocks(arr, rows)
+                if sp and isinstance(arr, np.ndarray):
                     h2d += origin_blocks.nbytes
-            origins[(u.req, u.key)] = (origin_blocks, meta)
+            origins[(u.req, u.key)] = (origin_blocks, final or meta)
             total = sum(s.n for s in u.segments)
             groups.setdefault(
-                (meta.num_blocks, _slot_bucket(total)), []
+                (int(origin_blocks.shape[0]), _slot_bucket(total)), []
             ).append(u)
 
         # dispatch every group first, keeping results on device; the host
@@ -447,7 +515,8 @@ def apply_delta_chains(
                 )
             else:
                 sp.set(launches=len(groups))
-            sp.set(leaves=len(units), h2d_bytes=h2d, d2h_bytes=d2h)
+            sp.set(leaves=len(units), h2d_bytes=h2d, d2h_bytes=d2h,
+                   grown_leaves=grown)
     return list(zip(outs, blocked_outs))
 
 

@@ -19,6 +19,7 @@ from __future__ import annotations
 import hashlib
 import os
 import tempfile
+import threading
 import zlib
 from pathlib import Path
 from typing import Dict, Optional
@@ -50,14 +51,20 @@ class Codec:
             raise ValueError(f"unknown codec backend {backend!r}")
         self.backend = backend
         self.level = level
-        if backend == "zstd":
-            self._c = zstandard.ZstdCompressor(level=level)
-            self._d = zstandard.ZstdDecompressor()
+        # zstd contexts are not thread-safe: each thread keeps its own
+        self._local = threading.local()
+
+    def _zstd(self) -> threading.local:
+        ctx = self._local
+        if not hasattr(ctx, "c"):
+            ctx.c = zstandard.ZstdCompressor(level=self.level)
+            ctx.d = zstandard.ZstdDecompressor()
+        return ctx
 
     def compress(self, payload: bytes) -> bytes:
         with _span("objects.compress"):
             if self.backend == "zstd":
-                return self._c.compress(payload)
+                return self._zstd().c.compress(payload)
             # zstd levels reach 22; zlib tops out at 9
             return zlib.compress(payload, min(self.level, 9))
 
@@ -70,9 +77,7 @@ class Codec:
                         "blob was written with zstd but zstandard is not "
                         "installed"
                     )
-                if self.backend == "zstd":
-                    return self._d.decompress(blob)
-                return zstandard.ZstdDecompressor().decompress(blob)
+                return self._zstd().d.decompress(blob)
             return zlib.decompress(blob)
 
     def compressed_size(self, payload: bytes) -> int:

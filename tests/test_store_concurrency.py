@@ -11,7 +11,9 @@ single writer thread; the store guarantees that depends on:
   metadata file reloads cleanly after any interleaving;
 * commits are pure appends: readers racing a committer always see either
   a complete old graph or a complete new one, and every tree served is
-  bit-identical to its committed payload.
+  bit-identical to its committed payload;
+* the store's one codec compresses and decompresses on many threads at
+  once.
 
 Threads + barriers only (no service layer here): this pins the *store*
 contract the service builds on, tier-1 fast.
@@ -22,6 +24,7 @@ import threading
 import numpy as np
 
 from repro.store import VersionStore
+from repro.store.objectstore import Codec
 
 
 def payload(seed: int, shape=(64, 48)):
@@ -264,3 +267,31 @@ class TestCacheUnderContention:
         s = store.materializer.stats()
         assert s["current_bytes"] <= int(one_entry * 2.5)
         assert s["current_bytes"] >= 0  # accounting never went negative
+
+
+class TestCodecSharedAcrossThreads:
+    def test_round_trips_from_eight_threads_at_once(self):
+        # zstd contexts are not thread-safe: one shared by every thread
+        # fails with ZstdError or crashes the process
+        codec = Codec(backend="zstd")
+        errors = []
+        barrier = threading.Barrier(8)
+
+        def worker(seed):
+            rng = np.random.default_rng(seed)
+            barrier.wait()
+            for _ in range(100):
+                blob = rng.integers(0, 4, 100_000, dtype=np.uint8).tobytes()
+                try:
+                    if codec.decompress(codec.compress(blob)) != blob:
+                        errors.append("round trip changed the bytes")
+                except Exception as e:  # noqa: BLE001 - collected, asserted below
+                    errors.append(repr(e))
+
+        threads = [threading.Thread(target=worker, args=(i,)) for i in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+            assert not t.is_alive()
+        assert errors == []

@@ -3,8 +3,10 @@
 Nothing runs: the TPU compiler, which is installed here, compiles for a chip
 that is described and not attached, and refuses what the chip would refuse
 (tiling, VMEM, layouts).  The sizes are those of ``chip_smoke.py``'s
-MiniCPM-2B-width checkpoint leaves.  ``interpret=False`` is passed
-explicitly: the process's default backend is still the CPU.
+MiniCPM-2B-width checkpoint leaves, and the padded block counts of the
+benchmark's YCSB-shaped table columns.  ``interpret=False`` is passed
+explicitly: the process's default backend is still the CPU.  The names the
+benchmark's trace reduction and span readers key on are pinned here too.
 """
 
 import functools
@@ -14,9 +16,11 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import obs
 from repro.kernels import ops
 from repro.kernels.block_diff import changed_block_mask
 from repro.kernels.chain_apply import (
@@ -24,6 +28,7 @@ from repro.kernels.chain_apply import (
     chain_delta_apply_batched,
 )
 from repro.kernels.ref import BLOCK_BYTES
+from repro.store.delta import apply_delta_chain, encode_delta
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 import chip_smoke  # noqa: E402
@@ -46,6 +51,13 @@ EMBED = _blocks("params/embed")      # 138098 blocks, 566 MB
 WQ = _blocks("params/layers/0/attn/wq")
 # slots of one fine-tune step on the embedding (~2% of its blocks), bucketed
 EMBED_SLOTS = 4096
+# a 200,000-record YCSB table growing by 50 rows a save: a 100 B field
+# column (4,883 blocks) and the int64 key column (391), padded as grown
+# leaves, with the compacted capacity of one 1,000-op batch's changed blocks
+# (~90 a field column, ~2 the key column: under each capacity's floor)
+TABLE_FIELD = ops.grown_blocks(ops.num_blocks_of(200_000 * 100))
+TABLE_KEY = ops.grown_blocks(ops.num_blocks_of(200_000 * 8))
+TABLE_SLOTS = {nb: ops.compact_capacity(1, nb) for nb in (TABLE_FIELD, TABLE_KEY)}
 HBM_BYTES = 16 * 10**9
 
 
@@ -143,3 +155,46 @@ def test_chain_delta_apply_batched(one_chip):
         sharding=one_chip,
     )
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("nb", [TABLE_FIELD, TABLE_KEY])
+def test_grown_table_column_kernels(one_chip, nb):
+    assert (TABLE_FIELD, TABLE_KEY) == (5120, 416)
+    assert TABLE_SLOTS == {5120: 256, 416: 16}
+    hlo = _compile(
+        functools.partial(changed_block_mask, interpret=False),
+        ((nb, 8, 128), jnp.int32), ((nb, 8, 128), jnp.int32),
+        sharding=one_chip,
+    )
+    assert "tpu_custom_call" in hlo
+    _compile(
+        functools.partial(ops._compact, capacity=TABLE_SLOTS[nb]),
+        ((nb, 1), jnp.int32), ((nb, 8, 128), jnp.int32),
+        sharding=one_chip,
+    )
+
+
+def test_diff_counter_names():
+    """The span readers key on these counters: ``changed_blocks``,
+    ``total_blocks``, ``grown_leaves`` and ``full_leaves`` on
+    ``delta.encode_delta`` (``commit_changed_blocks_pct``,
+    ``commit_grown_leaves``, ``commit_full_leaves``, ``compact_roofline``),
+    and ``grown_leaves`` on ``delta.apply_chains``."""
+    rng = np.random.default_rng(9)
+    v0 = {"a": rng.integers(0, 256, (60, 100), dtype=np.uint8),
+          "b": np.arange(9, dtype=np.int64), "c": np.zeros(4, np.float32)}
+    v1 = {"a": np.concatenate([v0["a"], v0["a"][:5] ^ 1]),
+          "b": np.arange(11, dtype=np.int64), "c": np.zeros(5, np.int32)}
+    tracer = obs.Tracer(enabled=True)
+    old = obs.set_tracer(tracer)
+    try:
+        payload, stats = encode_delta(v0, v1)
+        apply_delta_chain(v0, [payload])
+    finally:
+        obs.set_tracer(old)
+    (enc,) = [s for s in tracer.spans() if s.name == "delta.encode_delta"]
+    (app,) = [s for s in tracer.spans() if s.name == "delta.apply_chains"]
+    assert (enc.attrs["grown_leaves"], enc.attrs["full_leaves"]) == (2, 1)
+    assert enc.attrs["total_blocks"] == ops.num_blocks_of(6500) + 1
+    assert enc.attrs["changed_blocks"] == stats["changed_blocks"] > 0
+    assert app.attrs["grown_leaves"] == 2
