@@ -59,12 +59,48 @@ class Codec:
         if not hasattr(ctx, "c"):
             ctx.c = zstandard.ZstdCompressor(level=self.level)
             ctx.d = zstandard.ZstdDecompressor()
+            ctx.mt, ctx.workers = None, 0
         return ctx
 
+    def _workers(self, n: int) -> int:
+        """zstd workers for an ``n``-byte payload: one per zstd job it spans,
+        up to the CPUs this process may run on, or 0 (the one-threaded
+        frame) below two jobs or two CPUs; never 1, which is slower than 0.
+
+        The job is zstd's own default: 4 windows and at least 1 MiB
+        (``ZSTDMT_computeTargetJobLog``, long-distance matching off), the
+        window as zstd picks it for this level and source size.  At level 3
+        the window is 2 MiB for any source of 2 MiB or more, so a job is
+        8 MiB and payloads over 8 MiB take workers.  zstd's multi-threaded
+        frame does not depend on the worker count, so the bytes at rest
+        stay a function of the payload alone."""
+        window_log = zstandard.ZstdCompressionParameters.from_level(
+            self.level, source_size=n).window_log
+        jobs = -(-n // (1 << max(20, window_log + 2)))
+        if jobs < 2:
+            return 0
+        workers = min(len(os.sched_getaffinity(0)), jobs)
+        return workers if workers > 1 else 0
+
+    def _compressor(self, workers: int):
+        ctx = self._zstd()
+        if not workers:
+            return ctx.c
+        if ctx.workers != workers:
+            # one multi-threaded context a thread: its workers live as long
+            # as it does, and it is rebuilt only when the count changes
+            ctx.mt = zstandard.ZstdCompressor(level=self.level, threads=workers)
+            ctx.workers = workers
+        return ctx.mt
+
     def compress(self, payload: bytes) -> bytes:
-        with _span("objects.compress"):
-            if self.backend == "zstd":
-                return self._zstd().c.compress(payload)
+        with _span("objects.compress") as sp:
+            zstd = self.backend == "zstd"
+            workers = self._workers(len(payload)) if zstd else 0
+            if sp:
+                sp.set(workers=workers, in_bytes=len(payload))
+            if zstd:
+                return self._compressor(workers).compress(payload)
             # zstd levels reach 22; zlib tops out at 9
             return zlib.compress(payload, min(self.level, 9))
 

@@ -10,6 +10,7 @@ benchmark's trace reduction and span readers key on are pinned here too.
 """
 
 import functools
+import os
 import re
 import sys
 from pathlib import Path
@@ -29,6 +30,7 @@ from repro.kernels.chain_apply import (
 )
 from repro.kernels.ref import BLOCK_BYTES
 from repro.store.delta import apply_delta_chain, encode_delta
+from repro.store.objectstore import Codec
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 import chip_smoke  # noqa: E402
@@ -198,3 +200,22 @@ def test_diff_counter_names():
     assert enc.attrs["total_blocks"] == ops.num_blocks_of(6500) + 1
     assert enc.attrs["changed_blocks"] == stats["changed_blocks"] > 0
     assert app.attrs["grown_leaves"] == 2
+
+
+def test_compress_counter_names(monkeypatch):
+    """``commit_compress_mt_pct`` keys on ``workers`` (0 on the one-threaded
+    path) and ``in_bytes`` on ``objects.compress``."""
+    monkeypatch.setattr(os, "sched_getaffinity", lambda pid: {0, 1})
+    codec = Codec()
+    small, large = bytes(1000), bytes((8 << 20) + 1)  # one zstd job, two
+    tracer = obs.Tracer(enabled=True)
+    old = obs.set_tracer(tracer)
+    try:
+        codec.compress(small)
+        codec.compress(large)
+    finally:
+        obs.set_tracer(old)
+    got = [s.attrs for s in tracer.spans() if s.name == "objects.compress"]
+    mt = 2 if codec.backend == "zstd" else 0
+    assert got == [{"workers": 0, "in_bytes": 1000},
+                   {"workers": mt, "in_bytes": len(large)}]
