@@ -19,7 +19,6 @@ def main() -> None:
                     help="comma-separated prefixes to run (default: all)")
     args = ap.parse_args()
 
-    from . import delta_chain as dc
     from . import paper_figures as pf
     from . import serving_checkout as sc
     from . import serving_qps as sq
@@ -30,7 +29,6 @@ def main() -> None:
         ("solver_scale", ss.solver_scale),
         ("serving_checkout", sc.serving_checkout),
         ("serving_qps", sq.serving_qps),
-        ("delta_chain", dc.delta_chain),
         ("fig13", pf.fig13_tradeoff_directed),
         ("fig14", pf.fig14_maxrec_directed),
         ("fig15", pf.fig15_undirected),

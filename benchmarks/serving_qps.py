@@ -1,5 +1,5 @@
 """Sustained serving benchmark: open-loop zipfian traffic through the
-``DatasetService`` tier, mixed checkout/commit, chain vs global invalidation.
+``DatasetService`` tier, mixed checkout/commit.
 
 Where ``serving_checkout`` measures the store's raw materialization paths,
 this drives the *service* the way a client fleet would: requests arrive on
@@ -9,29 +9,22 @@ as a p99 cliff rather than silently throttling the workload), version
 popularity is zipfian, and a fraction of the traffic is commits appending
 fresh versions while checkouts keep hitting the old hot set.
 
-That interleaving is exactly the case the append-aware cache discipline
-exists for, so the same recorded workload runs twice over identical copies
-of the store:
+That interleaving is exactly the case the cache's per-entry decode-chain
+fingerprints exist for: a commit appends to the storage graph and
+invalidates nothing it can't reach, so the hot set stays warm across writes.
 
-* ``chain`` — per-entry decode-chain fingerprints; a commit appends to the
-  storage graph and invalidates nothing it can't reach, so the hot set
-  stays warm across writes;
-* ``global`` — the legacy whole-graph epoch; every commit rotates the
-  fingerprint and purges the cache wholesale.
-
-Acceptance: the chain run's warm hit rate is **strictly higher** than the
-global run's under any write traffic, and QPS/p99 move the same direction.
-Results (per-mode QPS, p50/p99, hit rate, coalescing/batching counters)
+Acceptance: no warm cache entry is invalidated while commits run
+(``invalidations == 0``), and the stage spans reconcile with the service's
+metrics.  Results (QPS, p50/p99, hit rate, coalescing/batching counters)
 append to ``BENCH_serving_qps.json``; the suite registers as
 ``serving_qps`` in ``benchmarks.run`` with a small n + short duration for
 CI smoke.
 
-Each measured run executes under an enabled :mod:`repro.obs` tracer: a
+The measured run executes under an enabled :mod:`repro.obs` tracer: a
 per-stage breakdown (queue wait / decode / device delta-apply span totals)
-and a span↔metrics reconciliation ratio land in the per-mode results, and
-both runs export into one Perfetto-loadable Chrome trace
-(``BENCH_serving_qps_trace.json``, chain = pid 1, global = pid 2) so a
-regression in the summary numbers can be opened as a timeline.
+and a span↔metrics reconciliation ratio land in the results, and the run
+exports a Perfetto-loadable Chrome trace (``BENCH_serving_qps_trace.json``)
+so a regression in the summary numbers can be opened as a timeline.
 
 Run standalone:
     PYTHONPATH=src python -m benchmarks.serving_qps [--n 400]
@@ -45,7 +38,6 @@ import argparse
 import asyncio
 import dataclasses
 import json
-import shutil
 import tempfile
 import time
 from pathlib import Path
@@ -220,14 +212,12 @@ def run_benchmark(
     seed: int = 0,
     trace_out: Optional[Path] = TRACE_PATH,
 ) -> Dict:
-    """Build one store, replay one workload under both invalidation modes.
-
-    Each mode's measured pass runs under its own enabled tracer; both export
-    into one Chrome trace at ``trace_out`` (chain = pid 1, global = pid 2;
-    ``None`` skips the artifact)."""
+    """Build one store and replay one workload over it, warm, under an
+    enabled tracer whose Chrome trace goes to ``trace_out`` (``None`` skips
+    the artifact)."""
     with tempfile.TemporaryDirectory(prefix="repro_qps_") as d:
-        base = Path(d) / "base"
-        store = build_store(str(base), n, seed=seed)
+        root = Path(d) / "store"
+        store = build_store(str(root), n, seed=seed)
         vids = sorted(store.versions)
         store.close()
         events = make_workload(
@@ -238,49 +228,30 @@ def run_benchmark(
             zipf_s=zipf_s,
             seed=seed + 3,
         )
-
-        modes: Dict[str, Dict] = {}
-        tracers: Dict[str, Tracer] = {}
-        for mode in ("chain", "global"):
-            root = Path(d) / mode
-            shutil.copytree(base, root)
-            repo = Repository(
-                str(root),
-                cache_invalidation=mode,
-                access_flush_every=_NO_FLUSH,
+        repo = Repository(str(root), access_flush_every=_NO_FLUSH)
+        # build_store commits at the store layer; give the service a branch
+        # tip for its write traffic to advance
+        if "main" not in repo.branches():
+            repo.branch("main", at=vids[-1])
+        # one warmup pass over the read set so the run starts hot; the
+        # measured pass then shows what write traffic costs (before the
+        # tracer installs — warmup decodes aren't the run)
+        repo.store.checkout_many(
+            sorted({e.vid for e in events if e.op == "checkout"})
+        )
+        tracer = Tracer(enabled=True, capacity=1 << 18)
+        old = set_tracer(tracer)
+        try:
+            run = asyncio.run(
+                run_traffic(repo, events, readers=readers, tracer=tracer)
             )
-            # build_store commits at the store layer; give the service a
-            # branch tip for its write traffic to advance
-            if "main" not in repo.branches():
-                repo.branch("main", at=vids[-1])
-            # one warmup pass over the read set so both modes start hot;
-            # the measured pass then shows what write traffic costs each
-            # (before the tracer installs — warmup decodes aren't the run)
-            repo.store.checkout_many(
-                sorted({e.vid for e in events if e.op == "checkout"})
-            )
-            tracer = Tracer(enabled=True, capacity=1 << 18)
-            old = set_tracer(tracer)
-            try:
-                modes[mode] = asyncio.run(
-                    run_traffic(
-                        repo, events, readers=readers, tracer=tracer
-                    )
-                )
-            finally:
-                set_tracer(old)
-            tracers[mode] = tracer
-            repo.close()
+        finally:
+            set_tracer(old)
+        repo.close()
 
     artifact = None
     if trace_out is not None:
-        merged = chrome_trace(
-            tracers["chain"], pid=1, process_name="serving_qps:chain"
-        )
-        chrome_trace(
-            tracers["global"], trace_out, pid=2,
-            process_name="serving_qps:global", base=merged,
-        )
+        chrome_trace(tracer, trace_out, process_name="serving_qps")
         artifact = str(trace_out)
 
     return {
@@ -289,11 +260,7 @@ def run_benchmark(
         "write_fraction": write_fraction,
         "zipf_s": zipf_s,
         "readers": readers,
-        "chain": modes["chain"],
-        "global": modes["global"],
-        "hit_rate_delta": round(
-            modes["chain"]["hit_rate"] - modes["global"]["hit_rate"], 4
-        ),
+        **run,
         "trace_artifact": artifact,
     }
 
@@ -311,19 +278,17 @@ def record(result: Dict, path: Path = BENCH_PATH) -> None:
 def serving_qps(n: int = 120, requests: int = 300, qps: float = 300.0) -> Iterable[Row]:
     """``benchmarks.run`` suite adapter — small n / short duration so the
     orchestrator and CI smoke stay bounded; the CLI runs the full sweep."""
-    result = run_benchmark(n, requests=requests, qps=qps)
-    record(result)
-    for mode in ("chain", "global"):
-        r = result[mode]
-        yield Row(
-            name=f"serving_qps/{mode}/n{n}",
-            us_per_call=1e6 / max(r["qps"], 1e-9),
-            derived=(
-                f"qps={r['qps']};p50={r['read_p50_ms']}ms;"
-                f"p99={r['read_p99_ms']}ms;hit={r['hit_rate']};"
-                f"coalesced={r['coalesced']};batches={r['batches']}"
-            ),
-        )
+    r = run_benchmark(n, requests=requests, qps=qps)
+    record(r)
+    yield Row(
+        name=f"serving_qps/n{n}",
+        us_per_call=1e6 / max(r["qps"], 1e-9),
+        derived=(
+            f"qps={r['qps']};p50={r['read_p50_ms']}ms;"
+            f"p99={r['read_p99_ms']}ms;hit={r['hit_rate']};"
+            f"coalesced={r['coalesced']};batches={r['batches']}"
+        ),
+    )
 
 
 def main() -> None:
@@ -353,12 +318,12 @@ def main() -> None:
     )
     record(result)
     print(json.dumps(result, indent=2))
-    ok = result["chain"]["hit_rate"] > result["global"]["hit_rate"]
-    ok_qps = result["chain"]["qps"] > 0 and result["chain"]["batches"] > 0
+    ok = result["commits"] > 0 and result["invalidations"] == 0
+    ok_qps = result["qps"] > 0 and result["batches"] > 0
     print(
-        f"# chain hit rate {result['chain']['hit_rate']} vs global "
-        f"{result['global']['hit_rate']} "
-        f"({'OK: append-aware strictly higher' if ok else 'REGRESSION'})"
+        f"# {result['invalidations']} warm entries invalidated across "
+        f"{result['commits']} commits, hit rate {result['hit_rate']} "
+        f"({'OK: commits kept the cache warm' if ok else 'REGRESSION'})"
     )
     ok_trace = True
     if result["trace_artifact"]:
@@ -369,11 +334,10 @@ def main() -> None:
     # span totals and ServiceMetrics tracks share one clock: ±5% or a stage
     # is being measured twice / not at all
     ok_recon = True
-    for mode in ("chain", "global"):
-        for stage, r in result[mode]["span_reconciliation"].items():
-            if r is not None and not (0.95 <= r <= 1.05):
-                ok_recon = False
-                print(f"# RECONCILIATION FAILURE {mode}/{stage}: {r}")
+    for stage, r in result["span_reconciliation"].items():
+        if r is not None and not (0.95 <= r <= 1.05):
+            ok_recon = False
+            print(f"# RECONCILIATION FAILURE {stage}: {r}")
     if not (ok and ok_qps and ok_trace and ok_recon):
         raise SystemExit(1)
 

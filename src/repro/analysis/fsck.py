@@ -21,11 +21,13 @@ Checks (rule ids):
     codec failure, malformed wire format).
 ``fsck.fingerprint`` (ERROR)
     Recomputed content fingerprint differs from the recorded one —
-    **bit-level payload corruption**.  The chain is re-decoded here
-    independently of the materialization cache: the cache key is the
-    storage-graph fingerprint (vid/base/key triples), which a bit flip
-    inside an object file does *not* change, so a cached tree would mask
-    exactly the corruption this check exists to find.  Sampled via
+    **bit-level payload corruption**, or a wrong decode.  The chain is
+    re-decoded here independently of the materialization cache: the cache
+    tag is the decode-chain fingerprint (vid/base/key triples), which a bit
+    flip inside an object file does *not* change, so a cached tree would
+    mask exactly the corruption this check exists to find.  Each hop runs
+    through :func:`~repro.store.delta.apply_delta_chain`, the decoder every
+    checkout reads through, so the check covers it too.  Sampled via
     ``sample=`` on big stores; full sweep by default.
 ``fsck.ref`` (ERROR)
     Branch/tag pointing at an unknown version; head naming a missing branch.
@@ -45,7 +47,7 @@ import hashlib
 from typing import TYPE_CHECKING, Dict, List, Optional, Sequence
 
 from ..core.solvers import CONSTRAINT_TOL
-from ..store.delta import FlatTree, apply_delta, decode_full, encode_full
+from ..store.delta import FlatTree, apply_delta_chain, decode_full, encode_full
 from .findings import Finding, Report, Severity
 
 if TYPE_CHECKING:  # pragma: no cover
@@ -85,8 +87,9 @@ def _sample_vids(vids: Sequence[int], sample: Optional[int]) -> List[int]:
 
 def _decode_chain(store: "VersionStore", vid: int,
                   memo: Dict[int, FlatTree]) -> FlatTree:
-    """Recreate ``vid`` straight from object payloads (no materializer cache;
-    see the ``fsck.fingerprint`` rationale in the module docstring)."""
+    """Recreate ``vid`` straight from object payloads, one hop at a time,
+    memoizing every tree on the way (no materializer cache; see the
+    ``fsck.fingerprint`` rationale in the module docstring)."""
     chain: List[int] = []
     v: Optional[int] = vid
     while v is not None and v not in memo:
@@ -96,7 +99,7 @@ def _decode_chain(store: "VersionStore", vid: int,
     for u in reversed(chain):
         payload = store.objects.get(store.versions[u].object_key)
         tree = decode_full(payload) if tree is None else \
-            apply_delta(tree, payload)
+            apply_delta_chain(tree, [payload])
         memo[u] = tree
     return memo[vid]
 

@@ -307,10 +307,9 @@ def check_dtype64_source(report: Report, module_name: str) -> None:
 #: {module: function qualnames} forming the materializer decode hot path;
 #: a device→host call *inside a loop* there serializes one sync per leaf
 HOT_PATH_FUNCTIONS: Dict[str, Tuple[str, ...]] = {
-    "repro.store.delta": ("apply_delta", "apply_delta_chains"),
+    "repro.store.delta": ("apply_delta_chains",),
     "repro.store.materializer": (
         "Materializer._execute_fused",
-        "Materializer._execute_stepwise",
         "Materializer._materialize_chain",
     ),
 }

@@ -23,11 +23,11 @@ pools, with three load-bearing mechanisms:
 
 * **Single-writer / multi-reader coordination** — commits serialize on a
   one-thread writer pool and may overlap with readers (a commit only
-  *appends* to the storage graph, and the append-aware cache keeps reader
-  state warm across it — see ``cache_invalidation="chain"`` on
-  ``VersionStore``).  ``repack`` and the background fsck sweep take the
-  exclusive side of an async reader-writer lock, quiescing every in-flight
-  request before the storage graph rewrites under them.  Each enqueued
+  *appends* to the storage graph, and the materialization cache's
+  per-entry chain fingerprints keep reader state warm across it — see
+  ``VersionStore.chain_fingerprint``).  ``repack`` and the background fsck
+  sweep take the exclusive side of an async reader-writer lock, quiescing
+  every in-flight request before the storage graph rewrites under them.  Each enqueued
   checkout additionally parks a read claim on its batch (released when the
   batch settles), so the quiesce covers pending/dispatching batches even
   when the requesters behind them were cancelled.

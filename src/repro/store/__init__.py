@@ -5,7 +5,6 @@ from .delta import (
     DeltaWire,
     RecreationCostModel,
     SparseLeafDelta,
-    apply_delta,
     apply_delta_chain,
     apply_delta_chains,
     decode_delta_wire,
@@ -41,7 +40,6 @@ __all__ = [
     "encode_full",
     "decode_full",
     "encode_delta",
-    "apply_delta",
     "apply_delta_chain",
     "apply_delta_chains",
     "decode_delta_wire",

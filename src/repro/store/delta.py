@@ -31,8 +31,8 @@ Because sparse entries carry content (not XOR), a K-step chain composes by
 **last-writer-wins per block row**, which :func:`apply_delta_chain` exploits:
 the chain's packed deltas are flattened *in chain order* into one padded
 device stack and applied in a single fused Pallas dispatch
-(:mod:`repro.kernels.chain_apply`), bit-identical to K sequential
-:func:`apply_delta` calls.  Per-leaf slot counts are bucketed to powers of
+(:mod:`repro.kernels.chain_apply`), bit-identical to applying the K deltas
+one after another.  Per-leaf slot counts are bucketed to powers of
 two (min 8) and leaves with equal ``(num_blocks, slot_bucket)`` are batched
 into one kernel launch, so jit caches are shared across chains of different
 lengths and sparsity — the same shape-bucketing discipline as
@@ -109,8 +109,8 @@ def encode_full(flat: FlatTree) -> bytes:
     # sorted-key serialization: the content fingerprint (sha256 of these
     # bytes) must not depend on dict insertion order, which differs between
     # the commit path (tree_flatten order) and checkout-re-encode
-    # (apply_delta rebuild order) — an order-dependent fp would spuriously
-    # invalidate the incremental Δ/Φ edge cache
+    # (apply_delta_chains rebuild order) — an order-dependent fp would
+    # spuriously invalidate the incremental Δ/Φ edge cache
     with _span("delta.encode_full"):
         return msgpack.packb(
             {"kind": "full",
@@ -282,42 +282,6 @@ def _zero_grown(arr: np.ndarray, shape: Tuple[int, ...]) -> np.ndarray:
     return out
 
 
-def apply_delta(base: FlatTree, payload: Union[bytes, DeltaWire]) -> FlatTree:
-    """Stepwise (one-hop) delta application — the reference recreation path.
-
-    Unchanged leaves pass through by reference; each changed leaf pays one
-    ``to_blocks``/``sparse_apply``/``from_blocks`` round trip, a grown leaf
-    in its padded block count.  Chains should use :func:`apply_delta_chain`,
-    which is bit-identical and fused.
-    """
-    wire = decode_delta_wire(payload) if isinstance(payload, bytes) else payload
-    out: FlatTree = {}
-    # device results accumulate here and come back in ONE batched transfer
-    # after the loop — a per-leaf np.asarray would serialize a device→host
-    # sync per changed leaf (the analysis host-sync lint flags exactly that)
-    pending: Dict[str, Tuple[jnp.ndarray, "ops.BlockMeta"]] = {}
-    for key, arr in base.items():
-        if key in wire.tombstones:
-            continue
-        d = wire.sparse.get(key)
-        meta = None if d is None or d.shape is None else _grown_meta(arr, d.shape)
-        if d is None or d.n == 0:
-            out[key] = arr if meta is None else _zero_grown(arr, d.shape)
-            continue
-        bb, same = ops.to_blocks(arr, meta and meta.num_blocks)
-        rec = ops.sparse_apply(bb, jnp.asarray(d.blocks), jnp.asarray(d.idx))
-        pending[key] = (rec, meta or same)
-    if pending:
-        # blocks come back as int32 and are viewed as the leaf's dtype on the
-        # host, which 64-bit dtypes survive
-        fetched = jax.device_get({k: rec for k, (rec, _) in pending.items()})
-        for key, (_, meta) in pending.items():
-            out[key] = ops.from_blocks(fetched[key], meta)
-    for key, wire_dict in wire.full.items():
-        out[key] = _arr_from_wire(wire_dict)
-    return out
-
-
 # ----------------------------------------------------- fused chain pipeline
 def _slot_bucket(n: int) -> int:
     """Pad per-leaf chain slot counts to powers of two (min 8) so the fused
@@ -395,9 +359,12 @@ def apply_delta_chains(
     A leaf that grew along its chain runs in the padded block count of its
     last shape, its origin zero-padded to it.
 
-    Returns ``[(tree, blocked)]`` per request, bit-identical to folding
-    :func:`apply_delta` over each chain.  ``stats`` (optional) is bumped
-    with ``launches`` / ``fused_slots`` for observability.
+    Returns ``[(tree, blocked)]`` per request, bit-identical to applying
+    each chain's deltas one hop at a time (pinned against the NumPy
+    reference decoder in ``tests/reference_decode.py``).  Leaves no step
+    touches pass through by reference from ``base_tree``.  ``stats``
+    (optional) is bumped with ``launches`` / ``fused_slots`` for
+    observability.
 
     Traced as ``delta.apply_chains``, with ``h2d_bytes`` (origin leaves
     uploaded to block form, and the padded slot stacks), ``d2h_bytes``
@@ -526,9 +493,9 @@ def apply_delta_chain(
     *,
     base_blocked: Optional[BlockedTree] = None,
 ) -> FlatTree:
-    """Fused K-step chain application (single chain): bit-identical to
-    ``functools.reduce(apply_delta, payloads, base)`` in one device dispatch
-    per leaf-shape group."""
+    """Fused K-step chain application (single chain): one device dispatch
+    per leaf-shape group (see :func:`apply_delta_chains`).  fsck calls it
+    with one payload at a time, to decode a chain hop by hop."""
     return apply_delta_chains([(base, payloads, base_blocked)])[0][0]
 
 

@@ -14,29 +14,28 @@ parts:
   raises instead of looping.
 
 * :class:`MaterializationCache` — a byte-budgeted LRU of materialized
-  FlatTrees keyed by vid, each entry tagged with the fingerprint it was
-  decoded under.  Under the default **append-aware** discipline
-  (``cache_invalidation="chain"``) the tag is the vid's own *decode-chain*
-  fingerprint — a hash over just the ``(vid, stored_base, object_key)``
-  triples along its storage chain — so a commit (which appends triples but
-  rewrites none) leaves every warm entry valid and interleaved save+serve
-  traffic stays warm; only an operation that rewrites chains (repack, which
-  purges wholesale) invalidates.  ``cache_invalidation="global"`` keeps the
-  legacy discipline: one whole-graph fingerprint, any commit or repack
-  rotates it and the cache drops everything.  Either way a stale tree can
-  never be served — an entry is only returned when its tag matches the live
+  FlatTrees keyed by vid, each entry tagged with its vid's *decode-chain*
+  fingerprint (:meth:`VersionStore.chain_fingerprint`, a hash over just the
+  ``(vid, stored_base, object_key)`` triples along its storage chain).  A
+  commit appends triples but rewrites none, so it leaves every warm entry
+  valid and interleaved save+serve traffic stays warm; an entry whose chain
+  was rewritten is dropped at its next lookup, and repack, which rewrites
+  chains wholesale, purges the cache.  A stale tree can never be served —
+  an entry is only returned when its tag matches the live chain
   fingerprint.  Cached arrays are marked read-only — a caller mutating a
   checkout result in place would otherwise silently corrupt every future
   checkout of that version.  Cache lookups, inserts and evictions take an
   internal lock so the service tier's reader threads can share one cache.
 
-* :class:`Materializer` — executes plans against the :class:`ObjectStore`,
-  feeding every decoded tree (intermediates included — they are exactly the
-  hot chain prefixes) through the cache, and exposes hit/decode statistics
-  plus ``prefetch`` for repack-time cache warming.  Commits feed the cache
-  too: :meth:`Materializer.keep` writes each committed tree through under
-  its new vid, so the next commit's parent (and a read of the new tip) is a
-  hit rather than a decode from disk.
+* :class:`Materializer` — executes plans against the :class:`ObjectStore`
+  through the fused, device-resident delta pipeline
+  (:func:`~repro.store.delta.apply_delta_chains`), feeding every
+  materialized tree (intermediates included — they are exactly the hot
+  chain prefixes) through the cache, and exposes hit/decode statistics plus
+  ``prefetch`` for repack-time cache warming.  Commits feed the cache too:
+  :meth:`Materializer.keep` writes each committed tree through under its
+  new vid, so the next commit's parent (and a read of the new tip) is a hit
+  rather than a decode from disk.
 
 The cache budget is the ``cache_budget_bytes`` knob on
 :class:`~repro.store.version_store.VersionStore` (default 256 MiB; 0 disables
@@ -47,12 +46,10 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import hashlib
 import threading
 from typing import (
     TYPE_CHECKING,
     Any,
-    Callable,
     Dict,
     Iterable,
     List,
@@ -68,7 +65,6 @@ from ..obs.tracer import span as _span
 from .delta import (
     BlockedTree,
     FlatTree,
-    apply_delta,
     apply_delta_chains,
     decode_full,
     payload_leaves,
@@ -81,18 +77,20 @@ if TYPE_CHECKING:  # pragma: no cover - import cycle guard (store owns us)
 def tree_nbytes(flat: FlatTree) -> int:
     """Resident bytes of a materialized tree (what the cache budget counts).
 
-    Charges every leaf in full even though ``apply_delta`` shares unchanged
-    leaves by reference between chain-adjacent trees — the budget over-, not
-    under-estimates resident memory, so eviction errs toward too early.
+    Charges every leaf in full even though ``apply_delta_chains`` passes
+    untouched leaves through by reference between chain-adjacent trees — the
+    budget over-, not under-estimates resident memory, so eviction errs
+    toward too early.
     """
     return sum(a.nbytes for a in flat.values())
 
 
 def _freeze(flat: FlatTree) -> FlatTree:
     """Mark every leaf read-only.  Materialized trees alias arrays — across
-    the cache, and across batch results via ``apply_delta``'s unchanged-leaf
-    passthrough — so an in-place write to one checkout result would silently
-    corrupt others; numpy turns that into a ValueError instead."""
+    the cache, and across batch results via ``apply_delta_chains``'s
+    untouched-leaf passthrough — so an in-place write to one checkout
+    result would silently corrupt others; numpy turns that into a
+    ValueError instead."""
     for arr in flat.values():
         arr.flags.writeable = False
     return flat
@@ -112,21 +110,6 @@ def _owned(leaf: Any, arr: np.ndarray) -> np.ndarray:
     if private and arr.flags.c_contiguous:
         return arr
     return np.array(arr, order="C")
-
-
-def storage_fingerprint(versions: Dict[int, Any]) -> str:
-    """Hash of the whole storage graph: every (vid, stored_base, object_key).
-
-    Any commit, repack, or metadata edit changes at least one triple, so a
-    cache keyed by this fingerprint can never serve a stale tree.
-    """
-    h = hashlib.sha256()
-    for vid in sorted(versions):
-        meta = versions[vid]
-        h.update(
-            f"{vid}:{meta.stored_base}:{meta.object_key};".encode()
-        )
-    return h.hexdigest()
 
 
 # ------------------------------------------------------------------- planner
@@ -211,34 +194,27 @@ class CheckoutPlanner:
 class MaterializationCache:
     """Byte-budgeted LRU of FlatTrees keyed by vid, fingerprint-validated.
 
-    Every entry is tagged with the fingerprint it was decoded under; a
-    lookup returns it only when the caller's fingerprint matches, so a stale
-    tree can never be served.  The tag discipline belongs to the
-    :class:`Materializer`:
+    Every entry is tagged with the decode-chain fingerprint it was
+    materialized under; a lookup returns it only when the caller's
+    fingerprint matches, and otherwise drops that entry alone
+    (``invalidations`` counts them) while the rest of the cache stays warm.
+    Commits never rotate the chain fingerprints of existing versions, so
+    interleaved commit+serve traffic keeps its hits; ``purge`` (repack)
+    drops everything.
 
-    * *chain* (append-aware) — tags are per-vid decode-chain fingerprints;
-      a mismatched entry is dropped individually (``invalidations`` counts
-      them) while the rest of the cache stays warm.  Commits never rotate
-      chain fingerprints of existing versions, so interleaved commit+serve
-      traffic keeps its hits; ``purge`` (repack) still drops everything.
-    * *global* (legacy) — tags are implicit: :meth:`ensure_fingerprint`
-      adopts one whole-graph fingerprint at a time and the first operation
-      under a new one drops every entry.
-
-    Entries come from checkouts (every decoded tree) and from commits (the
-    committed tree, through :meth:`Materializer.keep`).  They are evicted
-    least-recently-used once resident bytes exceed ``budget_bytes``; a tree
-    larger than the whole budget is simply not cached.  All access goes
-    through one internal lock — the service tier's reader threads share
-    this cache concurrently.
+    Entries come from checkouts (every materialized tree) and from commits
+    (the committed tree, through :meth:`Materializer.keep`).  They are
+    evicted least-recently-used once resident bytes exceed
+    ``budget_bytes``; a tree larger than the whole budget is simply not
+    cached.  All access goes through one internal lock — the service tier's
+    reader threads share this cache concurrently.
     """
 
     def __init__(self, budget_bytes: int) -> None:
         self.budget_bytes = int(budget_bytes)
         self._lock = threading.RLock()
-        self._fp: Optional[str] = None
         self._entries: (
-            "collections.OrderedDict[int, Tuple[FlatTree, int, Optional[str]]]"
+            "collections.OrderedDict[int, Tuple[FlatTree, int, str]]"
         ) = collections.OrderedDict()
         self.current_bytes = 0
         self.hits = 0
@@ -246,22 +222,6 @@ class MaterializationCache:
         self.evictions = 0
         self.invalidations = 0
         self.purges = 0
-
-    # -- fingerprint handling ------------------------------------------------
-    @property
-    def epoch(self) -> Optional[str]:
-        """The live whole-graph fingerprint (global discipline only)."""
-        return self._fp
-
-    def ensure_fingerprint(self, fp: str) -> None:
-        """Adopt ``fp`` as the live storage graph, clearing stale entries."""
-        with self._lock:
-            if fp != self._fp:
-                if self._entries:
-                    self.invalidations += 1
-                self._entries.clear()
-                self.current_bytes = 0
-                self._fp = fp
 
     def purge(self) -> None:
         """Drop every entry (repack rewrites chains wholesale; a full purge
@@ -271,26 +231,6 @@ class MaterializationCache:
                 self.purges += 1
             self._entries.clear()
             self.current_bytes = 0
-            self._fp = None
-
-    def valid_vids(self, fp_of: Callable[[int], Optional[str]]) -> List[int]:
-        """Vids whose entry tag matches ``fp_of(vid)``, dropping the rest.
-
-        An *explicit* validation sweep over every entry — the serving path
-        validates lazily at ``get`` instead; this is for introspection (the
-        service's warm-set accounting) and tests pinning tag semantics.
-        """
-        with self._lock:
-            stale = [
-                vid
-                for vid, ent in self._entries.items()
-                if ent[2] != fp_of(vid)
-            ]
-            for vid in stale:
-                _, nbytes, _ = self._entries.pop(vid)
-                self.current_bytes -= nbytes
-                self.invalidations += 1
-            return list(self._entries.keys())
 
     # -- lookup / insert -----------------------------------------------------
     def vids(self) -> List[int]:
@@ -301,14 +241,14 @@ class MaterializationCache:
         with self._lock:
             return vid in self._entries
 
-    def probe(self, vid: int, fp: Optional[str] = None) -> bool:
+    def probe(self, vid: int, fp: str) -> bool:
         """Non-counting validity check: is ``vid`` servable under ``fp``?"""
         with self._lock:
             ent = self._entries.get(vid)
             return ent is not None and ent[2] == fp
 
     def get(
-        self, vid: int, fp: Optional[str] = None, *, count: bool = True
+        self, vid: int, fp: str, *, count: bool = True
     ) -> Optional[FlatTree]:
         with self._lock:
             ent = self._entries.get(vid)
@@ -327,9 +267,7 @@ class MaterializationCache:
                 self.hits += 1
             return ent[0]
 
-    def put(
-        self, vid: int, tree: FlatTree, fp: Optional[str] = None
-    ) -> None:
+    def put(self, vid: int, tree: FlatTree, fp: str) -> None:
         if self.budget_bytes <= 0:
             return
         nbytes = tree_nbytes(tree)
@@ -380,39 +318,24 @@ class _Segment:
 class Materializer:
     """Executes checkout plans against the object store, through the cache.
 
-    With ``fuse_chains`` (the default) delta chains run through the
-    device-resident pipeline (:func:`repro.store.delta.apply_delta_chains`):
-    intermediate trees stay in blocked device form between steps, runs of
-    steps through vids nobody else needs collapse into single fused Pallas
-    dispatches, and same-shaped leaves across a batch's chains share kernel
-    launches.  Segment *endpoints* — vids that must exist as host trees —
-    are the requested vids, vids with multiple dependents in the plan, and
-    (when the cache budget is positive) every vid, so caching semantics are
-    unchanged: a warm cache sees exactly the trees it would have seen
-    stepwise.  ``fuse_chains=False`` keeps the legacy one-hop-at-a-time
-    path; both are bit-identical.
+    Delta chains run through the device-resident pipeline
+    (:func:`repro.store.delta.apply_delta_chains`): intermediate trees stay
+    in blocked device form between steps, runs of steps through vids nobody
+    else needs collapse into single fused Pallas dispatches, and
+    same-shaped leaves across a batch's chains share kernel launches.
+    Segment *endpoints* — vids that must exist as host trees — are the
+    requested vids, vids with multiple dependents in the plan, and (when
+    the cache budget is positive) every vid, so a warm cache holds every
+    tree along the chains it served.
 
     The store's commit path feeds the cache as well (:meth:`keep`): the tree
     just committed is what the next commit on the branch diffs against.
     """
 
-    def __init__(
-        self,
-        store: "VersionStore",
-        *,
-        budget_bytes: int,
-        fuse_chains: bool = True,
-        invalidation: str = "chain",
-    ) -> None:
-        if invalidation not in ("chain", "global"):
-            raise ValueError(
-                f"invalidation must be 'chain' or 'global', got {invalidation!r}"
-            )
+    def __init__(self, store: "VersionStore", *, budget_bytes: int) -> None:
         self._store = store
         self.planner = CheckoutPlanner(store)
         self.cache = MaterializationCache(budget_bytes)
-        self.fuse_chains = bool(fuse_chains)
-        self.invalidation = invalidation
         # decode counters; guarded by _stats_lock — reader-pool threads
         # share one Materializer and the serving benchmark reads these
         self._stats_lock = threading.Lock()
@@ -421,32 +344,9 @@ class Materializer:
         self.fused_segments = 0
         self.fused_stats: Dict[str, int] = {}
 
-    # -- fingerprint discipline ----------------------------------------------
-    def _entry_fp(self, vid: int) -> Optional[str]:
-        """The tag a cache entry for ``vid`` must carry to be servable."""
-        if self.invalidation == "chain":
-            return self._store.chain_fingerprint(vid)
-        return None  # global mode: validity is the epoch, entries untagged
-
-    def _cached_vids(self) -> List[int]:
-        """Vids the planner may treat as materialized (global mode rotates
-        the epoch first, purging on change).  The list is *optimistic* in
-        chain mode: entries are validated lazily at lookup — ``get`` drops a
-        stale tag, and the execute paths rebuild anything that vanished
-        between plan and execute — so a plan never pays a full-cache
-        fingerprint sweep."""
-        if self.invalidation == "global":
-            self.cache.ensure_fingerprint(self._store.storage_fingerprint())
-        return self.cache.vids()
-
     def probe(self, vid: int) -> bool:
         """Non-counting warm check: would ``checkout(vid)`` be a cache hit?"""
         try:
-            if self.invalidation == "global":
-                return (
-                    self.cache.epoch == self._store.storage_fingerprint()
-                    and vid in self.cache
-                )
             return self.cache.probe(vid, self._store.chain_fingerprint(vid))
         except (KeyError, RuntimeError):
             return False  # unknown vid / corrupted chain: not servable
@@ -466,7 +366,11 @@ class Materializer:
         """
         with _span("mat.checkout_many", vids=len(vids)) as msp:
             with _span("mat.plan") as psp:
-                plan = self.planner.plan(vids, cached=self._cached_vids())
+                # the cached vids are *optimistic*: entries are validated
+                # lazily at lookup — ``get`` drops a stale tag, and anything
+                # that vanished between plan and execute is rebuilt — so a
+                # plan never pays a full-cache fingerprint sweep
+                plan = self.planner.plan(vids, cached=self.cache.vids())
                 if psp:
                     psp.set(
                         steps=plan.decode_count,
@@ -478,7 +382,7 @@ class Materializer:
                 tree = trees.get(vid)
                 if tree is None:
                     tree = self.cache.get(
-                        vid, self._entry_fp(vid), count=False
+                        vid, self._store.chain_fingerprint(vid), count=False
                     )
                 if tree is None:
                     # the planner saw this vid cached but its entry was
@@ -505,18 +409,17 @@ class Materializer:
         """
         if self.cache.budget_bytes <= 0:
             return 0
-        self._cached_vids()  # global mode: rotate the epoch before warming
         warmed = 0
         # reversed: LRU evicts oldest inserts first, so load coldest→hottest
         for vid in reversed(list(vids)):
             # validated lookup, not bare containment: a stale-tagged entry
-            # (chain mode, e.g. an out-of-band metadata edit) must be
-            # dropped and re-warmed, not treated as present — and dropping
-            # it here also keeps the planner from calling it cached below
-            if self.cache.get(vid, self._entry_fp(vid), count=False) is not None:
+            # (e.g. an out-of-band metadata edit) must be dropped and
+            # re-warmed, not treated as present — and dropping it here also
+            # keeps the planner from calling it cached below
+            fp = self._store.chain_fingerprint(vid)
+            if self.cache.get(vid, fp, count=False) is not None:
                 continue
-            plan = self.planner.plan([vid], cached=self.cache.vids())
-            self._execute(plan)
+            self._execute(self.planner.plan([vid], cached=self.cache.vids()))
             warmed += 1
         return warmed
 
@@ -526,20 +429,15 @@ class Materializer:
 
         Call only once the commit is durable.  The entry is what a checkout
         of ``vid`` would cache: C-contiguous, read-only, keys sorted as
-        ``decode_full`` returns them, tagged with ``vid``'s entry
-        fingerprint.  A tree the budget cannot hold costs nothing.  Under
-        the global discipline the cache first adopts the post-commit epoch
-        (the next checkout would purge to it anyway), so the entry survives
-        until the next commit.
+        ``decode_full`` returns them, tagged with ``vid``'s chain
+        fingerprint.  A tree the budget cannot hold costs nothing.
         """
         cache = self.cache
         if cache.budget_bytes <= 0 or tree_nbytes(flat) > cache.budget_bytes:
             return False
         leaves = payload_leaves(payload)
         kept = {k: _owned(leaves[k], flat[k]) for k in sorted(flat)}
-        if self.invalidation == "global":
-            cache.ensure_fingerprint(self._store.storage_fingerprint())
-        cache.put(vid, _freeze(kept), self._entry_fp(vid))
+        cache.put(vid, _freeze(kept), self._store.chain_fingerprint(vid))
         return True
 
     def stats(self) -> Dict[str, int]:
@@ -560,10 +458,14 @@ class Materializer:
         sharing works even with a zero cache budget; everything decoded is
         also offered to the cache (budget permitting) for future requests.
         """
-        if self.fuse_chains:
-            trees = self._execute_fused(plan)
-        else:
-            trees = self._execute_stepwise(plan)
+        trees: Dict[int, FlatTree] = {}
+        for vid in plan.from_cache:
+            tree = self.cache.get(
+                vid, self._store.chain_fingerprint(vid), count=False
+            )
+            if tree is not None:
+                trees[vid] = tree
+        self._execute_fused(plan, trees)
         # hit/miss accounting per requested vid — under the cache lock, the
         # counters are shared with every reader-pool thread
         planned = {s.vid for s in plan.steps}
@@ -573,41 +475,12 @@ class Materializer:
             self.cache.hits += len(plan.requested) - n_miss
         return trees
 
-    def _load_cached(self, plan: CheckoutPlan) -> Dict[int, FlatTree]:
-        trees: Dict[int, FlatTree] = {}
-        for vid in plan.from_cache:
-            tree = self.cache.get(vid, self._entry_fp(vid), count=False)
-            if tree is not None:
-                trees[vid] = tree
-        return trees
-
-    def _execute_stepwise(self, plan: CheckoutPlan) -> Dict[int, FlatTree]:
-        """Legacy one-hop-at-a-time execution (``fuse_chains=False``)."""
-        objects = self._store.objects
-        with _span("mat.execute_stepwise", steps=len(plan.steps)) as esp:
-            trees = self._load_cached(plan)
-            n_full = n_delta = 0
-            for step in plan.steps:
-                if step.base is None:
-                    tree = decode_full(objects.get(step.object_key))
-                    n_full += 1
-                else:
-                    base_tree = trees.get(step.base)
-                    if base_tree is None:  # base evicted plan→execute
-                        base_tree = self._materialize_chain(step.base, trees)
-                    tree = apply_delta(base_tree, objects.get(step.object_key))
-                    n_delta += 1
-                trees[step.vid] = _freeze(tree)
-                self.cache.put(step.vid, tree, self._entry_fp(step.vid))
-            if esp:
-                esp.set(full_decodes=n_full, delta_applies=n_delta)
-        with self._stats_lock:
-            self.full_decodes += n_full
-            self.delta_applies += n_delta
-        return trees
-
-    def _execute_fused(self, plan: CheckoutPlan) -> Dict[int, FlatTree]:
-        """Segment-fused execution through the device-resident delta pipeline.
+    def _execute_fused(
+        self, plan: CheckoutPlan, trees: Dict[int, FlatTree]
+    ) -> None:
+        """Segment-fused execution through the device-resident delta
+        pipeline; adds every tree it materializes to ``trees``, which holds
+        the plan's starting trees.
 
         Delta steps are grouped into :class:`_Segment` runs ending at
         endpoints (requested vids, shared bases, every vid when caching);
@@ -619,8 +492,8 @@ class Materializer:
         ``to_blocks`` twice.
         """
         objects = self._store.objects
+        fp = self._store.chain_fingerprint
         with _span("mat.execute_fused", steps=len(plan.steps)) as esp:
-            trees = self._load_cached(plan)
             blocked: Dict[int, BlockedTree] = {}
             n_full = n_delta = n_segments = 0
             wave_stats: Dict[str, int] = {}
@@ -641,7 +514,7 @@ class Materializer:
                     tree = decode_full(objects.get(step.object_key))
                     n_full += 1
                     trees[step.vid] = _freeze(tree)
-                    self.cache.put(step.vid, tree, self._entry_fp(step.vid))
+                    self.cache.put(step.vid, tree, fp(step.vid))
                     continue
                 seg = open_at.pop(step.base, None)
                 if seg is None:
@@ -659,9 +532,8 @@ class Materializer:
             while pending:
                 ready = [s for s in pending if s.base in trees]
                 if not ready:
-                    # base evicted between plan and execute: stepwise
-                    # fallback rebuilds it (and anything under it), then the
-                    # wave retries
+                    # base evicted between plan and execute: rebuild it (and
+                    # anything under it), then the wave retries
                     self._materialize_chain(pending[0].base, trees)
                     continue
                 done = {id(s) for s in ready}
@@ -681,9 +553,7 @@ class Materializer:
                 for s, (tree, blk) in zip(ready, results):
                     trees[s.terminal] = _freeze(tree)
                     blocked[s.terminal] = blk
-                    self.cache.put(
-                        s.terminal, tree, self._entry_fp(s.terminal)
-                    )
+                    self.cache.put(s.terminal, tree, fp(s.terminal))
                     n_delta += len(s.steps)
                     n_segments += 1
             if esp:
@@ -699,27 +569,16 @@ class Materializer:
             self.fused_segments += n_segments
             for k, v in wave_stats.items():
                 self.fused_stats[k] = self.fused_stats.get(k, 0) + v
-        return trees
 
     def _materialize_chain(
         self, vid: int, trees: Dict[int, FlatTree]
     ) -> FlatTree:
-        """Fallback chain walk for a base missing from cache and plan."""
-        plan = self.planner.plan([vid], cached=trees.keys())
-        objects = self._store.objects
-        n_full = n_delta = 0
-        for step in plan.steps:
-            if step.base is None:
-                tree = decode_full(objects.get(step.object_key))
-                n_full += 1
-            else:
-                tree = apply_delta(
-                    trees[step.base], objects.get(step.object_key)
-                )
-                n_delta += 1
-            trees[step.vid] = _freeze(tree)
-            self.cache.put(step.vid, tree, self._entry_fp(step.vid))
-        with self._stats_lock:
-            self.full_decodes += n_full
-            self.delta_applies += n_delta
+        """Rebuild ``vid``, missing from cache and plan, into ``trees``.
+
+        Re-planned against the trees already built and run through the same
+        executor.  Nothing in the local dict can be evicted, so every base
+        of this plan is a full object or in ``trees``: the rebuild never
+        falls back again.
+        """
+        self._execute_fused(self.planner.plan([vid], cached=trees), trees)
         return trees[vid]

@@ -18,26 +18,23 @@ analyzes (§4.4, Appendix A).
 Checkout path (the recreation layer): every checkout routes through the
 :class:`~repro.store.materializer.Materializer` — a ``CheckoutPlanner`` that
 compiles one or many requested vids into a topologically ordered decode plan
-(shared storage-chain prefixes decoded exactly once), executed through a
-byte-budgeted LRU ``MaterializationCache`` of FlatTrees validated per entry
-against a fingerprint.  Under the default append-aware discipline
-(``cache_invalidation="chain"``) each entry is tagged with its vid's
-*decode-chain* fingerprint — a hash over just the ``(vid, stored_base,
-object_key)`` triples along that vid's storage chain — so a commit (which
-appends triples but rewrites none) keeps every warm entry alive and
-interleaved save+serve traffic never goes cold; ``repack`` rewrites chains
-and purges the cache wholesale.  ``cache_invalidation="global"`` keeps the
-legacy whole-graph fingerprint that any commit rotates (purging everything);
-either way a stale tree can never be served.  Each commit writes its tree
-through into the cache under the new vid (once it is durable, and only if
-it fits the budget), so the next commit's parent is a hit, not a decode
-from disk.  ``checkout`` serves hot
-versions from memory; ``checkout_many`` batches k checkouts into one plan,
-bit-identical to k sequential calls but strictly cheaper on chain-sharing
-batches.  The cache budget is the ``cache_budget_bytes`` constructor knob
-(default 256 MiB; 0 disables caching while keeping within-batch prefix
-sharing), and ``repack(use_access_frequencies=True)`` prefetches the hottest
-versions back into the cache after rewriting storage.
+(shared storage-chain prefixes decoded exactly once), executed through the
+fused device-resident delta pipeline and a byte-budgeted LRU
+``MaterializationCache`` of FlatTrees.  Each cache entry is tagged with its
+vid's *decode-chain* fingerprint (:meth:`VersionStore.chain_fingerprint`),
+so a commit (which appends ``(vid, stored_base, object_key)`` triples but
+rewrites none) keeps every warm entry alive and interleaved save+serve
+traffic never goes cold; ``repack`` rewrites chains and purges the cache
+wholesale, and a stale tree can never be served.  Each commit writes its
+tree through into the cache under the new vid (once it is durable, and only
+if it fits the budget), so the next commit's parent is a hit, not a decode
+from disk.  ``checkout`` serves hot versions from memory; ``checkout_many``
+batches k checkouts into one plan, bit-identical to k sequential calls but
+strictly cheaper on chain-sharing batches.  The cache budget is the
+``cache_budget_bytes`` constructor knob (default 256 MiB; 0 disables caching
+while keeping within-batch prefix sharing), and
+``repack(use_access_frequencies=True)`` prefetches the hottest versions back
+into the cache after rewriting storage.
 
 Concurrency: the store is single-writer / multi-reader.  Checkouts may run
 from several threads at once — the materialization cache takes its own lock,
@@ -75,7 +72,7 @@ import threading
 import time
 import warnings
 from pathlib import Path
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import msgpack
 
@@ -95,7 +92,6 @@ from .delta import (
 )
 from ..obs.tracer import span as _span
 from .materializer import Materializer
-from .materializer import storage_fingerprint as _storage_graph_fp
 from .objectstore import ObjectStore
 
 
@@ -164,8 +160,6 @@ class VersionStore:
         cache_budget_bytes: int = 256 << 20,
         access_flush_every: int = 64,
         prefetch_hot_k: int = 8,
-        fuse_chains: bool = True,
-        cache_invalidation: str = "chain",
     ) -> None:
         self.root = Path(root)
         self.root.mkdir(parents=True, exist_ok=True)
@@ -186,20 +180,11 @@ class VersionStore:
         # current branch; owned by the Repository facade, persisted in the
         # msgpack metadata so they survive a close/reopen like version metas
         self.refs: Dict[str, Any] = {"branches": {}, "tags": {}, "head": "main"}
-        # recreation layer: planner + byte-budgeted FlatTree LRU; fuse_chains
-        # routes delta chains through the fused device-resident pipeline,
-        # cache_invalidation picks the append-aware ("chain") or legacy
-        # whole-graph ("global") fingerprint discipline
-        self.materializer = Materializer(
-            self,
-            budget_bytes=cache_budget_bytes,
-            fuse_chains=fuse_chains,
-            invalidation=cache_invalidation,
-        )
+        # recreation layer: planner + byte-budgeted FlatTree LRU
+        self.materializer = Materializer(self, budget_bytes=cache_budget_bytes)
         self.access_flush_every = access_flush_every
         self.prefetch_hot_k = prefetch_hot_k
         self._unflushed_accesses = 0
-        self._storage_fp: Optional[str] = None
         # record of the last repack's spec + outcome, persisted with the
         # metadata: fsck re-validates the recorded constraints against the
         # *current* storage graph (a post-repack mutation that silently
@@ -269,10 +254,8 @@ class VersionStore:
                     content_fp=content_fp,
                 )
                 # a commit only *appends* a (vid, stored_base, object_key)
-                # triple: the whole-graph fingerprint rotates (global-mode
-                # caches purge) but every existing decode chain is untouched,
-                # so append-aware caches stay warm
-                self._storage_fp = None
+                # triple: every existing decode chain is untouched, so warm
+                # cache entries stay valid
                 if update_branch is not None:
                     self.refs["branches"][update_branch] = vid
                 self._save_meta()
@@ -295,17 +278,6 @@ class VersionStore:
         return vid
 
     # ------------------------------------------------------------ checkout
-    def storage_fingerprint(self) -> str:
-        """Hash of every (vid, stored_base, object_key) triple — the
-        whole-graph cache epoch used by ``cache_invalidation="global"`` and
-        fsck.  Changes on commit and repack, never within a read-only
-        workload.  (The default ``"chain"`` discipline uses
-        :meth:`chain_fingerprint` instead, which commits do *not* rotate.)"""
-        with self._lock:
-            if self._storage_fp is None:
-                self._storage_fp = _storage_graph_fp(self.versions)
-            return self._storage_fp
-
     def chain_fingerprint(self, vid: int) -> str:
         """Fingerprint of ``vid``'s decode chain: a rolling hash over the
         ``(vid, stored_base, object_key)`` triples from ``vid`` down to its
@@ -640,10 +612,8 @@ class VersionStore:
                     )
                 meta.object_key = key
                 meta.stored_bytes = stored
-            # storage graph rewritten: new epoch, every chain fingerprint is
-            # dead — repack keeps the wholesale purge (append-aware
-            # invalidation only spares *commits* the purge)
-            self._storage_fp = None
+        # storage graph rewritten: chain fingerprints rotated wholesale, so
+        # purging beats dropping each stale entry at its next lookup
         self.materializer.cache.purge()
 
     def gc(self) -> int:
@@ -729,7 +699,6 @@ class VersionStore:
             "head": str(refs.get("head", "main")),
         }
         self.last_repack = obj.get("last_repack") or None
-        self._storage_fp = None  # metadata replaced: recompute lazily
 
     # -------------------------------------------------------------- limits
     def log(self) -> List[VersionMeta]:

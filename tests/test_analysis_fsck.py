@@ -8,19 +8,29 @@ Corruption injections (one per test, each asserting the exact finding set):
 * bit-flipped stored payload   → ``fsck.unreadable`` ERROR (codec refuses)
 * crafted wrong-content payload→ ``fsck.fingerprint`` ERROR (decodes fine,
   bytes differ — the cache-bypass case)
+* delta with a wrong block index → ``fsck.fingerprint`` ERROR (decodes fine)
 * dangling branch ref          → ``fsck.ref`` ERROR
 * constraint drift post-repack → ``fsck.constraint`` ERROR
 * orphaned object              → ``fsck.orphan-object`` WARNING
+
+A clean store's chains are re-decoded hop by hop through
+``apply_delta_chain``, the decoder checkouts read through, and each hop must
+equal the NumPy reference decoder's, for every kind of leaf a save carries.
 """
 
+import ml_dtypes
+import msgpack
 import numpy as np
 import pytest
 
+from repro.analysis import fsck as fsck_mod
 from repro.analysis.cli import build_synthetic_store
 from repro.analysis.findings import Severity
 from repro.core import OptimizeSpec
-from repro.store import Repository
+from repro.store import Repository, VersionStore
 from repro.store.delta import flatten_payload
+
+from reference_decode import apply_delta_ref, checkout_ref
 
 
 def payload(seed: int, shape=(48, 32)):
@@ -82,6 +92,93 @@ class TestCleanStores:
         assert report.checked["fsck.fingerprint"] == 2
 
 
+def _edit(a, step):
+    """``a`` with its element ``step`` (in C order) changed."""
+    out = a.copy()
+    out.reshape(-1)[step] += 1
+    return out
+
+
+def _versions(kind, n=4):
+    """``n`` successive trees of one leaf kind, beside a large float32 leaf
+    that one element of each version changes, so every save is a delta."""
+    rng = np.random.default_rng(len(kind))
+    big = rng.standard_normal(20_000).astype(np.float32)
+    if kind == "float32":
+        leaves = {"w": rng.standard_normal((48, 64)).astype(np.float32)}
+    elif kind == "bfloat16":
+        leaves = {"h": rng.standard_normal((64, 64)).astype(ml_dtypes.bfloat16)}
+    elif kind == "float64+int64":
+        leaves = {"col": rng.standard_normal(3000),
+                  "ids": rng.integers(2**40, 2**50, 2000, dtype=np.int64)}
+    elif kind == "grown":
+        leaves = {"field": rng.integers(0, 256, (300, 100), dtype=np.uint8),
+                  "num_rows": np.array(300, np.int64)}
+    else:  # "tombstone" / "rewrite": a small leaf beside the edited one
+        leaves = {"w": rng.standard_normal((48, 64)).astype(np.float32),
+                  "small": np.arange(10.0, dtype=np.float32).reshape(2, 5)}
+    trees = []
+    for step in range(n):
+        if step:
+            leaves = {k: _edit(a, step) if a.ndim and k != "small" else a
+                      for k, a in leaves.items()}
+            big = big.copy()
+            big[step] = -step
+        if kind == "grown" and step:
+            field = np.concatenate(
+                [leaves["field"], np.full((5 * step, 100), step, np.uint8)])
+            leaves = {"field": field, "num_rows": np.array(len(field), np.int64)}
+        if kind == "tombstone" and step == 2:
+            leaves = {k: a for k, a in leaves.items() if k != "small"}
+        if kind == "rewrite" and step == 2:
+            leaves = {**leaves, "small": np.arange(12, dtype=np.int16)}
+        if kind == "rewrite" and step == 3:
+            leaves = {**leaves, "small": _edit(leaves["small"], 3)}
+        trees.append({"big": big, **leaves})
+    return trees
+
+
+LEAF_KINDS = ["float32", "bfloat16", "float64+int64", "grown", "tombstone",
+              "rewrite"]
+
+
+@pytest.mark.parametrize("kind", LEAF_KINDS)
+def test_clean_fsck_decodes_through_the_chain_pipeline(tmp_path, monkeypatch,
+                                                       kind):
+    store = VersionStore(tmp_path)
+    trees = _versions(kind)
+    vids = [store.commit(trees[0])]
+    for t in trees[1:]:
+        vids.append(store.commit(t, parents=[vids[-1]]))
+    assert all(store.versions[v].stored_base == p
+               for p, v in zip(vids, vids[1:]))
+    hops = []
+    decode = fsck_mod.apply_delta_chain
+
+    def spy(base, payloads):
+        got = decode(base, payloads)
+        (payload,) = payloads  # one hop at a time
+        want = apply_delta_ref(base, payload)
+        assert set(got) == set(want)
+        for k in want:
+            assert got[k].dtype == want[k].dtype, k
+            assert np.array_equal(got[k], want[k]), k
+        hops.append(payload)
+        return got
+
+    monkeypatch.setattr(fsck_mod, "apply_delta_chain", spy)
+    report = store.fsck()
+    assert report.findings == [], "\n".join(f.render() for f in report.findings)
+    assert report.checked["fsck.fingerprint"] == len(vids)
+    assert len(hops) == len(vids) - 1
+    for vid, want in zip(vids, trees):
+        got = checkout_ref(store, vid)
+        assert set(got) == set(want)
+        for k in want:
+            assert got[k].dtype == want[k].dtype, k
+            assert np.array_equal(got[k], want[k]), k
+
+
 class TestInjectedCorruption:
     def test_stored_base_cycle(self, repo):
         store = repo.store
@@ -133,6 +230,22 @@ class TestInjectedCorruption:
         rm = rule_map(store.fsck())
         assert rm["fsck.fingerprint"] == [(f"v{tip}", Severity.ERROR)]
         assert "fsck.unreadable" not in rm
+
+    def test_wrong_block_index_flunks_fingerprint(self, repo):
+        # the tip's delta still decodes, but writes its changed block at the
+        # neighbouring row: only the recomputed content fingerprint sees it
+        store = repo.store
+        tip = max(store.versions)
+        obj = msgpack.unpackb(
+            store.objects.get(store.versions[tip].object_key), raw=False)
+        entry = obj["sparse"]["w"]
+        idx = np.frombuffer(entry["idx"], np.int32)
+        assert list(idx) == [0]
+        entry["idx"] = (idx + 1).tobytes()
+        _obj_path(store, tip).write_bytes(store.objects.codec.compress(
+            msgpack.packb(obj, use_bin_type=True)))
+        rm = rule_map(store.fsck())
+        assert rm == {"fsck.fingerprint": [(f"v{tip}", Severity.ERROR)]}
 
     def test_dangling_branch_ref(self, repo):
         repo.store.refs["branches"]["ghost"] = 999

@@ -1,9 +1,7 @@
 """Fused delta-chain pipeline tests: kernel vs stepwise oracle, wire decode
 round-trips, and a randomized chain property suite (mixed sparse/full/
 tombstone steps, all dtypes incl. bfloat16) asserting the fused path is
-bit-identical to folding ``apply_delta`` step by step."""
-
-import functools
+bit-identical to the NumPy reference decoder applied step by step."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +11,8 @@ from repro.kernels import ops
 from repro.kernels.chain_apply import chain_delta_apply, chain_delta_apply_batched
 from repro.kernels.ref import chain_delta_apply_ref
 from repro.store import delta as D
+
+from reference_decode import apply_delta_ref, decode_chain_ref
 
 
 def _rand_blocks(rng, n):
@@ -120,7 +120,7 @@ class TestChainProperty:
     def test_fused_bitidentical_to_stepwise(self, seed):
         rng = np.random.RandomState(seed)
         base, payloads = _random_chain(rng, steps=rng.randint(1, 8))
-        want = functools.reduce(D.apply_delta, payloads, base)
+        want = decode_chain_ref(base, payloads)
         got = D.apply_delta_chain(base, payloads)
         _assert_trees_bitequal(want, got)
 
@@ -129,7 +129,7 @@ class TestChainProperty:
         chains = [_random_chain(rng, steps=4) for _ in range(3)]
         results = D.apply_delta_chains([(b, p, None) for b, p in chains])
         for (b, p), (tree, blocked) in zip(chains, results):
-            _assert_trees_bitequal(functools.reduce(D.apply_delta, p, b), tree)
+            _assert_trees_bitequal(decode_chain_ref(b, p), tree)
             # blocked companion covers exactly the kernel-path leaves
             for k, (blk, meta) in blocked.items():
                 np.testing.assert_array_equal(
@@ -144,7 +144,7 @@ class TestChainProperty:
             k: ops.to_blocks(jnp.asarray(v)) for k, v in base.items()
         }
         got = D.apply_delta_chain(base, payloads, base_blocked=blocked)
-        _assert_trees_bitequal(functools.reduce(D.apply_delta, payloads, base), got)
+        _assert_trees_bitequal(decode_chain_ref(base, payloads), got)
 
 
 class TestDeltaWire:
@@ -154,10 +154,10 @@ class TestDeltaWire:
         cur = base
         for p in payloads:
             wire = D.decode_delta_wire(p)
-            _assert_trees_bitequal(
-                D.apply_delta(cur, p), D.apply_delta(cur, wire)
-            )
-            cur = D.apply_delta(cur, wire)
+            want = apply_delta_ref(cur, p)
+            _assert_trees_bitequal(want, D.apply_delta_chain(cur, [p]))
+            _assert_trees_bitequal(want, D.apply_delta_chain(cur, [wire]))
+            cur = want
 
     def test_wire_fields(self):
         base = {"a": np.ones((8, 8), np.float32), "b": np.ones(4, np.float32)}

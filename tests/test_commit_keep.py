@@ -1,12 +1,12 @@
 """A commit writes its tree through into the materialization cache.
 
-* the next commit's parent is a cache hit: no decode, no delta apply;
+* the next commit's parent is a cache hit: no decode, no delta apply —
+  for dense leaves, grown table columns and 64-bit leaves alike;
 * the kept tree is what a checkout from the reopened store returns: keys,
   dtypes (bf16 included), shapes and bytes, C-contiguous and read-only;
 * the caller's arrays are copied, not aliased, and keep their flags; a
   ``jax.Array``'s host value is kept without a second copy;
 * a tree the budget cannot hold is not copied and leaves no entry;
-* under the global discipline the tip survives until the next commit;
 * a commit that fails leaves no entry;
 * checkouts of old versions stay correct while commits run.
 """
@@ -37,9 +37,49 @@ def _next(tree, k: int):
     return {**tree, "w": w, "step": tree["step"] + 1}
 
 
+def _table(seed: int):
+    rng = np.random.RandomState(seed)
+    return {
+        "field": rng.randint(0, 256, (300, 100)).astype(np.uint8),
+        "num_rows": np.array(300, dtype=np.int64),
+    }
+
+
+def _next_table(tree, k: int):
+    """``tree`` with ``k`` rows appended and one old row rewritten."""
+    field = np.concatenate([tree["field"], np.full((k, 100), k, np.uint8)])
+    field[k] = 255 - k
+    return {"field": field, "num_rows": np.array(len(field), np.int64)}
+
+
+def _wide(seed: int):
+    rng = np.random.RandomState(seed)
+    return {
+        "col": rng.randn(3000),
+        "ids": rng.randint(2**40, 2**50, size=2000, dtype=np.int64),
+    }
+
+
+def _next_wide(tree, k: int):
+    """``tree`` with changes a 32-bit copy could not represent."""
+    col, ids = tree["col"].copy(), tree["ids"].copy()
+    col[k] *= 1 + 1e-12
+    ids[k] += 1
+    return {"col": col, "ids": ids}
+
+
+# (first tree, next tree) per kind of leaf a save carries
+TREES = {
+    "dense": (_tree, _next),
+    "grown": (_table, _next_table),
+    "64bit": (_wide, _next_wide),
+}
+
+
 def _cached(store, vid):
-    mat = store.materializer
-    return mat.cache.get(vid, mat._entry_fp(vid), count=False)
+    return store.materializer.cache.get(
+        vid, store.chain_fingerprint(vid), count=False
+    )
 
 
 def _assert_same(got, want):
@@ -60,15 +100,14 @@ def _traced(fn):
     return out, tracer.spans()
 
 
-@pytest.mark.parametrize("invalidation", ["chain", "global"])
-@pytest.mark.parametrize("fuse", [True, False])
-def test_next_commit_parent_is_a_hit(tmp_path, invalidation, fuse):
-    store = VersionStore(tmp_path, cache_invalidation=invalidation,
-                         fuse_chains=fuse)
-    t1 = _tree(1)
+@pytest.mark.parametrize("tree", TREES)
+def test_next_commit_parent_is_a_hit(tmp_path, tree):
+    first, step = TREES[tree]
+    store = VersionStore(tmp_path)
+    t1 = first(1)
     v1 = store.commit(t1)
     before = store.materializer.stats()
-    t2 = _next(t1, 1)
+    t2 = step(t1, 1)
     v2, spans = _traced(lambda: store.commit(t2, parents=[v1]))
     after = store.materializer.stats()
     assert after["full_decodes"] == before["full_decodes"]
@@ -84,9 +123,13 @@ def test_next_commit_parent_is_a_hit(tmp_path, invalidation, fuse):
     (keep,) = [s for s in spans if s.name == "store.keep"]
     assert keep.parent_id == commit.span_id and keep.attrs["kept"] == 1
     # and the chain goes on: the delta-stored tip is the next parent
-    v3 = store.commit(_next(t2, 2), parents=[v2])
+    t3 = step(t2, 2)
+    v3 = store.commit(t3, parents=[v2])
+    assert store.versions[v3].stored_base == v2
     assert store.materializer.stats()["full_decodes"] == before["full_decodes"]
-    _assert_same(store.checkout(v3), _next(t2, 2))
+    _assert_same(store.checkout(v3), t3)
+    # what was kept is what the objects on disk decode to
+    _assert_same(VersionStore(tmp_path).checkout(v3), t3)
 
 
 def test_kept_tree_equals_a_checkout_from_disk(tmp_path):
@@ -144,20 +187,6 @@ def test_tree_over_budget_is_not_kept(tmp_path, budget):
     assert keep.attrs["kept"] == 0
     assert vid not in store.materializer.cache
     assert store.materializer.cache.current_bytes == 0
-
-
-def test_global_discipline_tip_survives_until_next_commit(tmp_path):
-    store = VersionStore(tmp_path, cache_invalidation="global")
-    t1 = _tree(4)
-    v1 = store.commit(t1)
-    assert store.materializer.probe(v1)
-    v2 = store.commit(_next(t1, 1), parents=[v1])
-    # the next commit rotates the epoch: the old tip goes, the new one stays
-    assert store.materializer.cache.vids() == [v2]
-    assert store.materializer.probe(v2)
-    hits = store.materializer.stats()["hits"]
-    _assert_same(store.checkout(v2), _next(t1, 1))
-    assert store.materializer.stats()["hits"] == hits + 1
 
 
 def test_failed_commit_leaves_no_entry(tmp_path, monkeypatch):

@@ -1,10 +1,8 @@
 """Leaves whose leading axis grows between versions (rows appended to a
 table's columns) are stored as block-sparse deltas and rebuilt bit for bit
-by every read path: stepwise ``apply_delta``, the fused chain, the
-materializer and fsck.  The reference is plain NumPy: the parent's rows
-with the new rows concatenated, then the updated rows scattered in."""
-
-import functools
+by every read path: the fused chain, the materializer and fsck, and by the
+NumPy reference decoder.  The reference save is plain NumPy: the parent's
+rows with the new rows concatenated, then the updated rows scattered in."""
 
 import ml_dtypes
 import numpy as np
@@ -14,11 +12,12 @@ from repro.kernels import ops
 from repro.kernels.block_diff import changed_block_mask
 from repro.store import VersionStore
 from repro.store.delta import (
-    apply_delta,
     apply_delta_chain,
     decode_delta_wire,
     encode_delta,
 )
+
+from reference_decode import apply_delta_ref, decode_chain_ref
 
 BF16 = np.dtype(ml_dtypes.bfloat16)
 
@@ -94,8 +93,9 @@ def test_grown_leaf_reads_back_through_every_path(leaf, growth, tmp_path):
     d = wire.sparse["leaf"]
     assert 0 < d.n <= 2 * 3 + 1 + n1 - n0 and np.all(d.idx < n1)
 
-    _equal(apply_delta(v0, p1), v1)
-    _equal(functools.reduce(apply_delta, [p1, p2], v0), v2)
+    _equal(apply_delta_ref(v0, p1), v1)
+    _equal(decode_chain_ref(v0, [p1, p2]), v2)
+    _equal(apply_delta_chain(v0, [p1]), v1)
     _equal(apply_delta_chain(v0, [p1, p2]), v2)
 
     store = VersionStore(tmp_path, cache_budget_bytes=0)
@@ -122,7 +122,7 @@ def test_grown_leaf_with_no_block_changed(leaf):
     assert _blocks(v1["leaf"]) == _blocks(v0["leaf"])
     payload, stats = encode_delta(v0, v1)
     assert (stats["changed_blocks"], stats["grown_leaves"]) == (0, 1)
-    _equal(apply_delta(v0, payload), v1)
+    _equal(apply_delta_ref(v0, payload), v1)
     _equal(apply_delta_chain(v0, [payload]), v1)
     v2 = {"leaf": _grow(np.random.default_rng(4), v1["leaf"], 2)}
     _equal(apply_delta_chain(v0, [payload, encode_delta(v1, v2)[0]]), v2)
@@ -167,7 +167,7 @@ def test_leaf_that_did_not_grow_is_stored_whole(new_shape, new_dtype):
     assert (stats["full_leaves"], stats["grown_leaves"]) == (1, 0)
     wire = decode_delta_wire(payload)
     assert set(wire.full) == {"leaf"} and wire.sparse == {}
-    _equal(apply_delta(v0, payload), v1)
+    _equal(apply_delta_ref(v0, payload), v1)
     _equal(apply_delta_chain(v0, [payload]), v1)
 
 
@@ -181,7 +181,7 @@ def test_one_row_growths_share_one_padded_block_count():
     for _ in range(32):
         new = {"col": _grow(rng, tree["col"], 1, updates=1)}
         payload, _ = encode_delta(tree, new)
-        _equal(apply_delta(tree, payload), new)
+        _equal(apply_delta_ref(tree, payload), new)
         tree = new
     assert changed_block_mask._cache_size() - before <= 2
 
@@ -199,7 +199,7 @@ def test_wandering_changed_counts_share_one_compact_program():
         new = {"col": _grow(rng, tree["col"], 1, updates=updates)}
         payload, stats = encode_delta(tree, new)
         counts.add(stats["changed_blocks"])
-        _equal(apply_delta(tree, payload), new)
+        _equal(apply_delta_ref(tree, payload), new)
         tree = new
     assert min(counts) <= 8 < 16 < max(counts) <= 32
     assert ops._compact._cache_size() - before == 1

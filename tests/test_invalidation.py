@@ -1,22 +1,19 @@
 """Append-aware cache invalidation semantics, pinned end to end.
 
-The load-bearing store change of the service tier: cache entries carry a
+The load-bearing store property of the service tier: cache entries carry a
 fingerprint of *their own* decode chain (the ``(vid, stored_base,
-object_key)`` triples down to the full root) instead of one global
-storage-graph epoch.  Contract under test:
+object_key)`` triples down to the full root).  Contract under test:
 
 * a **commit** appends versions but rewrites no existing chain, so it must
   not evict any warm entry it can't reach — on any branch, under any
   parent topology, with zero invalidation counters moving;
 * a **repack** rewrites chains wholesale and must still purge everything;
-* correctness is pinned against a stepwise zero-budget oracle store:
-  whatever the cache discipline keeps or drops, served trees stay
-  bit-identical to a from-scratch decode;
+* correctness is pinned against the stepwise NumPy reference decoder:
+  whatever the cache keeps or drops, served trees stay bit-identical to a
+  from-scratch decode of the same storage graph;
 * **chain fingerprints** change exactly when the entry's own chain
   changes (metadata edits to *other* chains leave them fixed) and the
   stale entry is dropped lazily at lookup;
-* the legacy ``cache_invalidation="global"`` mode still purges on every
-  commit (the baseline the serving benchmark compares against);
 * the byte-budget LRU keeps working unchanged underneath the tags.
 """
 
@@ -25,6 +22,8 @@ import pytest
 
 from repro.core import OptimizeSpec
 from repro.store import VersionStore
+
+from reference_decode import checkout_ref
 
 
 def payload(seed: int, shape=(64, 48)):
@@ -92,37 +91,6 @@ class TestCommitKeepsWarmEntries:
         assert s1["hits"] - s0["hits"] == len(hot)
         assert s1["invalidations"] == 0 and s1["purges"] == 0
 
-    def test_global_epoch_still_rotates_underneath(self, tmp_path):
-        """The fsck/audit epoch (storage_fingerprint) must keep rotating on
-        commit even though the chain-mode cache no longer keys off it."""
-        store, trees, vids = build_branching(tmp_path)
-        fp0 = store.storage_fingerprint()
-        chain_fps = {v: store.chain_fingerprint(v) for v in vids}
-        store.commit(perturbed(trees[vids[-1]], 9), parents=[vids[-1]],
-                     message="append")
-        assert store.storage_fingerprint() != fp0
-        for v in vids:
-            assert store.chain_fingerprint(v) == chain_fps[v]
-
-    def test_global_mode_purges_on_commit(self, tmp_path):
-        store, trees, vids = build_branching(
-            tmp_path, cache_invalidation="global"
-        )
-        store.checkout_many(list(vids))
-        store.commit(perturbed(trees[vids[-1]], 9), parents=[vids[-1]],
-                     message="append")
-        s0 = store.materializer.stats()
-        t = store.checkout(vids[0])
-        s1 = store.materializer.stats()
-        assert np.array_equal(t["w"], trees[vids[0]]["w"])
-        assert s1["misses"] == s0["misses"] + 1  # cold again: epoch rotated
-        # epoch rotation counts as an invalidation event (purges is repack's)
-        assert s1["invalidations"] >= 1
-
-    def test_invalid_mode_rejected(self, tmp_path):
-        with pytest.raises(ValueError, match="invalidation"):
-            VersionStore(tmp_path, cache_invalidation="sometimes")
-
 
 class TestRepackPurges:
     def test_repack_purges_wholesale_and_trees_survive(self, tmp_path):
@@ -183,10 +151,9 @@ class TestOracleParity:
             # interleave reads so the cache is warm while the graph grows
             store.checkout(vids[rng.randint(0, len(vids))])
 
-        oracle = VersionStore(root, cache_budget_bytes=0, fuse_chains=False)
         for v in vids:
             got = store.checkout(v)
-            want = oracle.checkout(v)
+            want = checkout_ref(store, v)
             assert set(got) == set(want)
             for k in want:
                 assert np.array_equal(got[k], want[k]), (v, k)

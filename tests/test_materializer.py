@@ -5,14 +5,14 @@ persistence, cycle guards, and repack gc accounting."""
 import numpy as np
 import pytest
 
-from repro.store import VersionStore
+from repro.store import VersionStore, flatten_payload
 from repro.store.materializer import (
     CheckoutPlanner,
     MaterializationCache,
-    storage_fingerprint,
     tree_nbytes,
 )
 
+from reference_decode import checkout_ref
 from test_store import build_linear_history, make_payload, perturb
 
 
@@ -38,9 +38,38 @@ def build_branching_store(tmp_path, *, n=12, branch_every=3, seed=0,
     return store, vids, payloads
 
 
+def build_history(tmp_path, tree, *, n=6, budget=256 << 20):
+    """A linear history of ``n`` versions, each after the first stored as a
+    delta; returns the store, the vids and the tip's tree.  ``dense``
+    perturbs a fixed-shape payload; ``grown`` appends rows to a table's
+    columns beside an int64 row count, so every column leaf grows."""
+    store = VersionStore(tmp_path, cache_budget_bytes=budget)
+    if tree == "dense":
+        vids, last = build_linear_history(store, n=n, shape=(64, 64))
+        return store, vids, flatten_payload(last)
+    rng = np.random.default_rng(5)
+
+    def table(field):
+        rows = len(field)
+        return {"field": field, "key": np.arange(rows, dtype=np.int64) * 3,
+                "num_rows": np.array(rows, np.int64)}
+
+    t = table(rng.integers(0, 256, (600, 100), dtype=np.uint8))
+    vids = [store.commit(t)]
+    for i in range(n - 1):
+        new = rng.integers(0, 256, (7 + i, 100), dtype=np.uint8)
+        field = np.concatenate([t["field"], new])
+        field[rng.integers(0, len(t["field"]), 3)] = i
+        t = table(field)
+        vids.append(store.commit(t, parents=[vids[-1]]))
+    assert all(store.versions[v].stored_base is not None for v in vids[1:])
+    return store, vids, t
+
+
 def assert_trees_equal(a, b):
     assert set(a) == set(b)
     for k in a:
+        assert a[k].dtype == b[k].dtype, k
         np.testing.assert_array_equal(a[k], b[k])
 
 
@@ -64,8 +93,6 @@ class TestCheckoutManyProperty:
                 assert_trees_equal(got, fresh.checkout(vid))
 
     def test_batch_matches_committed_payloads(self, tmp_path):
-        from repro.store import flatten_payload
-
         store, vids, payloads = build_branching_store(tmp_path, n=8, seed=7)
         out = store.checkout_many(vids)
         for vid, tree in zip(vids, out):
@@ -118,50 +145,31 @@ class TestMaterializationCache:
         # checkout of an old version is a pure cache hit
         store = VersionStore(tmp_path)
         vids, payload = build_linear_history(store, n=4)
-        fp1 = store.storage_fingerprint()
         chain_fp = store.chain_fingerprint(vids[-1])
         store.checkout(vids[-1])
         assert len(store.materializer.cache.vids()) > 0
         rng = np.random.RandomState(9)
         store.commit(perturb(payload, rng), parents=[vids[-1]])
-        assert store.storage_fingerprint() != fp1  # global epoch rotates...
-        assert store.chain_fingerprint(vids[-1]) == chain_fp  # ...chains don't
+        assert store.chain_fingerprint(vids[-1]) == chain_fp
         m = store.materializer
         d0, f0 = m.delta_applies, m.full_decodes
         store.checkout(vids[0])
         assert (m.delta_applies, m.full_decodes) == (d0, f0)  # no decode
         assert m.cache.invalidations == 0
 
-    def test_commit_invalidates_cache_global_mode(self, tmp_path):
-        # legacy discipline stays available as the purge-everything baseline
-        store = VersionStore(tmp_path, cache_invalidation="global")
-        vids, payload = build_linear_history(store, n=4)
-        fp1 = store.storage_fingerprint()
-        store.checkout(vids[-1])
-        assert len(store.materializer.cache.vids()) > 0
-        rng = np.random.RandomState(9)
-        store.commit(perturb(payload, rng), parents=[vids[-1]])
-        fp2 = store.storage_fingerprint()
-        assert fp1 != fp2
-        store.checkout(vids[0])  # first op under the new fingerprint
-        assert store.materializer.cache.invalidations >= 1
-
     def test_repack_invalidates_cache_and_serves_fresh(self, tmp_path):
         store = VersionStore(tmp_path)
         vids, _ = build_linear_history(store, n=5)
         warm = {v: store.checkout(v) for v in vids}
-        fp1 = store.storage_fingerprint()
+        fps = {v: store.chain_fingerprint(v) for v in vids}
         store.repack("spt")  # materializes everything: new storage graph
-        assert store.storage_fingerprint() != fp1
+        # every delta-stored version's chain was rewritten; the full root's
+        # object is the same bytes, so its chain is unchanged
+        rotated = [v for v in vids if store.chain_fingerprint(v) != fps[v]]
+        assert rotated == vids[1:]
+        assert store.materializer.stats()["purges"] == 1
         for v in vids:
             assert_trees_equal(store.checkout(v), warm[v])
-
-    def test_fingerprint_pure_function_of_triples(self, tmp_path):
-        store = VersionStore(tmp_path)
-        build_linear_history(store, n=3)
-        assert store.storage_fingerprint() == storage_fingerprint(store.versions)
-        reopened = VersionStore(tmp_path)
-        assert reopened.storage_fingerprint() == store.storage_fingerprint()
 
     def test_byte_budget_evicts_lru(self, tmp_path):
         store = VersionStore(tmp_path)
@@ -177,8 +185,6 @@ class TestMaterializationCache:
     def test_zero_budget_cache_still_correct(self, tmp_path):
         store = VersionStore(tmp_path, cache_budget_bytes=0)
         vids, last_payload = build_linear_history(store, n=4)
-        from repro.store import flatten_payload
-
         assert_trees_equal(
             store.checkout(vids[-1]), flatten_payload(last_payload)
         )
@@ -192,8 +198,9 @@ class TestMaterializationCache:
             tree["w"][0, 0] = 1.0  # mutating would corrupt the shared cache
 
     def test_zero_budget_arrays_also_read_only(self, tmp_path):
-        # regression: apply_delta shares unchanged leaves across a batch's
-        # results, so even uncached trees must be frozen — a writable alias
+        # regression: the chain pipeline passes untouched leaves through by
+        # reference across a batch's results, so even uncached trees must be
+        # frozen — a writable alias
         # would let one result's mutation corrupt another's
         store = VersionStore(tmp_path, cache_budget_bytes=0)
         vids, _ = build_linear_history(store, n=3)
@@ -321,42 +328,37 @@ class TestPlanner:
     def test_cache_standalone_lru_order(self):
         cache = MaterializationCache(budget_bytes=100)
         t = lambda: {"x": np.zeros(10, np.float32)}  # 40 bytes
-        cache.ensure_fingerprint("fp")
-        cache.put(1, t())
-        cache.put(2, t())
-        cache.get(1)  # refresh 1: now 2 is LRU
-        cache.put(3, t())  # over budget: evicts 2
+        cache.put(1, t(), "fp1")
+        cache.put(2, t(), "fp2")
+        cache.get(1, "fp1")  # refresh 1: now 2 is LRU
+        cache.put(3, t(), "fp3")  # over budget: evicts 2
         assert 1 in cache and 3 in cache and 2 not in cache
 
 
 class TestFusedPipeline:
     """The fused device-resident chain path must be invisible semantically:
-    bit-identical trees, stepwise-equivalent decode counters, same fallback
-    behavior when a planned-cached base is evicted mid-flight."""
+    trees bit-identical to a stepwise NumPy decode, decode counters that
+    count each planned decode once, and a base evicted mid-flight rebuilt
+    through the same executor."""
 
     @pytest.mark.parametrize("budget", [0, 256 << 20])
     def test_fused_equals_stepwise(self, tmp_path, budget):
         store, vids, _ = build_branching_store(
             tmp_path / "a", n=10, seed=11, cache_budget_bytes=budget
         )
-        fused = VersionStore(
-            tmp_path / "a", cache_budget_bytes=budget, fuse_chains=True
-        )
-        stepwise = VersionStore(
-            tmp_path / "a", cache_budget_bytes=budget, fuse_chains=False
-        )
-        for got, want in zip(
-            fused.checkout_many(vids), stepwise.checkout_many(vids)
-        ):
-            assert_trees_equal(got, want)
-        # fusion must not change what the accounting reports
-        f, s = fused.materializer.stats(), stepwise.materializer.stats()
-        for key in ("full_decodes", "delta_applies", "hits", "misses"):
-            assert f[key] == s[key], key
+        cold = VersionStore(tmp_path / "a", cache_budget_bytes=budget)
+        for vid, got in zip(vids, cold.checkout_many(vids)):
+            assert_trees_equal(got, checkout_ref(cold, vid))
+        # every version decoded exactly once, counted as the walk would
+        n_full = sum(cold.versions[v].stored_base is None for v in vids)
+        s = cold.materializer.stats()
+        assert (s["full_decodes"], s["delta_applies"]) == (
+            n_full, len(vids) - n_full)
+        assert (s["hits"], s["misses"]) == (0, len(vids))
 
     def test_whole_chain_fuses_at_zero_budget(self, tmp_path):
         # budget 0 and a single requested tip: the whole delta chain is one
-        # segment — one fused launch wave, counters still stepwise-equivalent
+        # segment — one fused launch wave, one delta apply per hop
         store = VersionStore(tmp_path, cache_budget_bytes=0)
         vids, _ = build_linear_history(store, n=6, shape=(64, 64))
         m = store.materializer
@@ -365,27 +367,29 @@ class TestFusedPipeline:
         assert m.delta_applies - d0 == len(vids) - 1
         assert m.fused_segments - seg0 == 1
 
-    @pytest.mark.parametrize("fuse", [True, False])
-    def test_evicted_base_fallback(self, tmp_path, fuse):
+    @pytest.mark.parametrize("tree", ["dense", "grown"])
+    @pytest.mark.parametrize("budget", [0, 256 << 20])
+    def test_evicted_base_fallback(self, tmp_path, budget, tree):
         # a vid the planner saw as cached can be evicted before _execute
-        # runs (concurrent checkouts sharing one cache); both paths must
-        # rebuild it via the stepwise _materialize_chain fallback
-        store = VersionStore(tmp_path, fuse_chains=fuse)
-        vids, _ = build_linear_history(store, n=6, shape=(64, 64))
+        # runs (concurrent checkouts sharing one cache); the executor
+        # rebuilds it through _materialize_chain, itself a fused plan
+        store, vids, tip = build_history(tmp_path, tree, budget=budget)
         m = store.materializer
-        m.cache.ensure_fingerprint(store.storage_fingerprint())
         store.checkout(vids[2])  # warms the chain prefix through vids[2]
-        plan = m.planner.plan([vids[-1]], cached=m.cache.vids())
+        plan = m.planner.plan([vids[-1]], cached=[vids[2]])
         assert vids[2] in plan.from_cache
-        m.cache._entries.clear()  # evict everything between plan and execute
-        m.cache.current_bytes = 0
+        m.cache.purge()  # evict everything between plan and execute
+        s0 = m.stats()
         trees = m._execute(plan)
-        want = VersionStore(tmp_path, cache_budget_bytes=0).checkout(vids[-1])
-        assert_trees_equal(trees[vids[-1]], want)
+        assert_trees_equal(trees[vids[-1]], tip)
+        assert_trees_equal(trees[vids[-1]], checkout_ref(store, vids[-1]))
+        # the rebuild decodes vids[0..2] once, the plan the rest
+        s1 = m.stats()
+        assert s1["full_decodes"] - s0["full_decodes"] == 1
+        assert s1["delta_applies"] - s0["delta_applies"] == len(vids) - 1
 
     @pytest.mark.parametrize("budget", [0, 256 << 20])
-    @pytest.mark.parametrize("fuse", [True, False])
-    def test_64bit_leaves_bit_exact(self, tmp_path, fuse, budget):
+    def test_64bit_leaves_bit_exact(self, tmp_path, budget):
         # float64/int64 leaves (NumPy's defaults for dataset columns) keep
         # every byte through commit -> delta -> checkout; with x64 off, an
         # upload before the byte view would narrow them to 32 bits.  The 0-d
@@ -393,9 +397,7 @@ class TestFusedPipeline:
         rng = np.random.RandomState(3)
         col = rng.randn(5000)
         ids = rng.randint(2**40, 2**50, size=3000, dtype=np.int64)
-        store = VersionStore(
-            tmp_path, cache_budget_bytes=budget, fuse_chains=fuse
-        )
+        store = VersionStore(tmp_path, cache_budget_bytes=budget)
         trees, vids = [], []
         for step in range(4):
             col, ids = col.copy(), ids.copy()
@@ -407,9 +409,7 @@ class TestFusedPipeline:
             trees.append({"col": col, "ids": ids, "step": step_ctr})
             vids.append(store.commit(trees[-1], parents=vids[-1:]))
         assert all(store.versions[v].stored_base is not None for v in vids[1:])
-        cold = VersionStore(
-            tmp_path, cache_budget_bytes=budget, fuse_chains=fuse
-        )
+        cold = VersionStore(tmp_path, cache_budget_bytes=budget)
         # the tip cold (whole chain), then every version in one batch
         got = [cold.checkout(vids[-1])] + cold.checkout_many(vids)
         for tree, want in zip(got, trees[-1:] + trees):
@@ -418,12 +418,12 @@ class TestFusedPipeline:
                 assert np.array_equal(tree[k], want[k]), k
 
     def test_fused_trees_frozen_and_cached(self, tmp_path):
-        store = VersionStore(tmp_path, fuse_chains=True)
+        store = VersionStore(tmp_path)
         vids, _ = build_linear_history(store, n=4, shape=(64, 64))
         tree = store.checkout(vids[-1])
         with pytest.raises(ValueError):
             tree["w"][0, 0] = 1.0
-        # warm-cache semantics unchanged under fusion: intermediates cached
+        # warm-cache semantics: intermediates are cached too
         m = store.materializer
         d0, f0 = m.delta_applies, m.full_decodes
         store.checkout(vids[2])

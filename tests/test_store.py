@@ -10,12 +10,14 @@ from repro.checkpoint import PreemptionGuard, VersionedCheckpointManager
 from repro.store import (
     ObjectStore,
     VersionStore,
-    apply_delta,
+    apply_delta_chain,
     decode_full,
     encode_delta,
     encode_full,
     flatten_payload,
 )
+
+from reference_decode import apply_delta_ref
 
 
 def make_payload(rng, scale=1.0, shape=(64, 96)):
@@ -24,6 +26,18 @@ def make_payload(rng, scale=1.0, shape=(64, 96)):
         "b": rng.randn(shape[1]).astype(np.float32),
         "emb": {"table": rng.randn(128, 32).astype(np.float32)},
     }
+
+
+def apply_hop(base, payload):
+    """One delta hop through the store's decoder, which must agree with the
+    NumPy reference decoder."""
+    got = apply_delta_chain(base, [payload])
+    want = apply_delta_ref(base, payload)
+    assert set(got) == set(want)
+    for k in want:
+        assert got[k].dtype == want[k].dtype, k
+        np.testing.assert_array_equal(got[k], want[k])
+    return got
 
 
 def perturb(payload, rng, frac=0.05):
@@ -66,7 +80,7 @@ class TestDeltaCodec:
         new = flatten_payload(new_p)
         payload, stats = encode_delta(base, new)
         assert stats["changed_blocks"] < stats["total_blocks"]
-        rec = apply_delta(base, payload)
+        rec = apply_hop(base, payload)
         for k in new:
             np.testing.assert_array_equal(rec[k], new[k])
 
@@ -86,7 +100,7 @@ class TestDeltaCodec:
         del new["b"]
         new["extra"] = rng.randn(7, 7).astype(np.float32)
         payload, _ = encode_delta(base, new)
-        rec = apply_delta(base, payload)
+        rec = apply_hop(base, payload)
         assert "b" not in rec and "extra" in rec
         np.testing.assert_array_equal(rec["extra"], new["extra"])
 
@@ -95,7 +109,7 @@ class TestDeltaCodec:
         base = flatten_payload(make_payload(rng))
         new = dict(base)
         new["w"] = rng.randn(10, 10).astype(np.float32)
-        rec = apply_delta(base, encode_delta(base, new)[0])
+        rec = apply_hop(base, encode_delta(base, new)[0])
         np.testing.assert_array_equal(rec["w"], new["w"])
 
 
@@ -224,7 +238,7 @@ class TestVersionStore:
         p1 = make_payload(rng)
         v1 = store.commit(p1, message="v1")
         # v2 must be stored as a *delta* (checkout then rebuilds the tree in
-        # apply_delta order): small perturbation + one added leaf whose key
+        # delta-decode order): small perturbation + one added leaf whose key
         # sorts before the base keys
         p2 = perturb(p1, rng, frac=0.03)
         p2["aa_added"] = rng.randn(16).astype(np.float32)
