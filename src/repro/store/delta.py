@@ -14,8 +14,9 @@ state, dataset shards) either fully or as a delta against a base payload:
 * new / shrunk / otherwise reshaped leaves → stored whole;
 * deleted leaves → tombstones.
 
-Wire format is msgpack; zstd happens in the object store.  The codec also
-returns the *measured* Δ (serialized bytes) and a Φ estimate from
+Wire format is msgpack (a delta written by :func:`_pack_frame`, byte for
+byte what ``msgpack.packb`` writes); zstd happens in the object store.  The
+codec also returns the *measured* Δ (serialized bytes) and a Φ estimate from
 :class:`RecreationCostModel` — these feed the paper's cost matrices, keeping
 Δ and Φ genuinely distinct quantities (Scenario 3: Φ ≠ Δ).
 
@@ -92,12 +93,58 @@ def _path_str(p) -> str:
 
 
 # --------------------------------------------------------------------- wire
-def _arr_to_wire(a: np.ndarray) -> Dict:
+def _arr_to_wire(a: np.ndarray, as_bytes: bool = True) -> Dict:
+    """A leaf's wire dict; with ``as_bytes`` False its ``data`` is the
+    array itself, for :func:`_pack_frame` to copy into place."""
     return {
         "dtype": a.dtype.str if a.dtype != jnp.bfloat16 else "bfloat16",
         "shape": list(a.shape),
-        "data": a.tobytes(),
+        "data": a.tobytes() if as_bytes else np.asarray(a),
     }
+
+
+def _bin_header(n: int) -> bytes:
+    """msgpack's header of an ``n``-byte bin (bin8, bin16 or bin32)."""
+    if n <= 0xFF:
+        return b"\xc4" + n.to_bytes(1, "big")
+    if n <= 0xFFFF:
+        return b"\xc5" + n.to_bytes(2, "big")
+    return b"\xc6" + n.to_bytes(4, "big")
+
+
+def _pack_frame(obj: Dict) -> memoryview:
+    """``msgpack.packb(obj, use_bin_type=True)``, byte for byte, where each
+    NumPy array among ``obj``'s dict values stands for a bin of its C-order
+    bytes, written with one copy of each array.
+
+    The small parts (map headers, keys, ints, lists of ints or strings) come
+    from ``msgpack.Packer`` and each array gets a bin header; their lengths
+    size one buffer, left unfilled until every part is copied into place.
+    Returns a view of that buffer, which ``len``, ``hashlib``, the codec
+    and ``msgpack.unpackb`` take as they take ``bytes``."""
+    packer = msgpack.Packer(use_bin_type=True)
+    parts: List[Union[bytes, np.ndarray]] = []
+
+    def walk(o: Any) -> None:
+        if isinstance(o, dict):
+            parts.append(packer.pack_map_header(len(o)))
+            for k, v in o.items():
+                parts.append(packer.pack(k))
+                walk(v)
+        elif isinstance(o, np.ndarray):
+            parts.append(_bin_header(o.nbytes))
+            parts.append(np.ascontiguousarray(o).reshape(-1).view(np.uint8))
+        else:
+            parts.append(packer.pack(o))
+
+    walk(obj)
+    out = np.empty(sum(len(p) for p in parts), np.uint8)
+    at = 0
+    for p in parts:
+        out[at : at + len(p)] = (np.frombuffer(p, np.uint8)
+                                 if isinstance(p, bytes) else p)
+        at += len(p)
+    return out.data
 
 
 def _arr_from_wire(d: Dict) -> np.ndarray:
@@ -133,12 +180,19 @@ def _grew(base: np.ndarray, new: np.ndarray) -> bool:
             and base.shape[1:] == new.shape[1:] and base.shape[0] < new.shape[0])
 
 
-def encode_delta(base: FlatTree, new: FlatTree) -> Tuple[bytes, Dict]:
+def encode_delta(base: FlatTree, new: FlatTree) -> Tuple[memoryview, Dict]:
     """Delta payload turning `base` into `new`, plus stats for Φ modelling.
 
     A leaf whose leading axis grew is diffed against the parent zero-padded
     to the new size (both in :func:`repro.kernels.ops.grown_blocks` blocks),
     and its wire entry carries the new ``shape``.
+
+    The payload is ``msgpack.packb`` of ``{"kind", "sparse", "full",
+    "tombstones"}`` byte for byte (bins for ``idx``, ``blocks`` and a whole
+    leaf's ``data``), laid out by :func:`_pack_frame`: each fetched
+    ``idx[:n]`` / ``blocks[:n]`` and each leaf stored whole is copied once,
+    into one buffer sized in advance.  It comes back as a ``memoryview`` of
+    that buffer, not as ``bytes``.
 
     Traced as ``delta.encode_delta``; for each leaf diffed on the device,
     ``delta.upload`` (host staging and the *enqueue* of both leaves'
@@ -166,7 +220,7 @@ def encode_delta(base: FlatTree, new: FlatTree) -> Tuple[bytes, Dict]:
             grown = b is not None and _grew(b, arr)
             if b is None or (not grown and (b.shape != arr.shape
                                             or b.dtype != arr.dtype)):
-                full[key] = _arr_to_wire(arr)
+                full[key] = _arr_to_wire(arr, as_bytes=False)
                 stats["full_leaves"] += 1
                 continue
             real = ops.num_blocks_of(arr.nbytes)
@@ -204,15 +258,13 @@ def encode_delta(base: FlatTree, new: FlatTree) -> Tuple[bytes, Dict]:
                 # device-side artifact)
                 host_idx = np.asarray(idx[:n], np.int32)
                 host_blocks = np.asarray(blocks[:n], np.int32)
-                entry["idx"] = host_idx.tobytes()
-                entry["blocks"] = host_blocks.tobytes()
+                entry["idx"], entry["blocks"] = host_idx, host_blocks
             if sp:
                 d2h += fetched if grown else host_idx.nbytes + host_blocks.nbytes
         with _span("delta.pack"):
-            payload = msgpack.packb(
+            payload = _pack_frame(
                 {"kind": "delta", "sparse": sparse, "full": full,
-                 "tombstones": tombstones},
-                use_bin_type=True,
+                 "tombstones": tombstones}
             )
         if sp:
             sp.set(h2d_bytes=h2d, d2h_bytes=d2h, **stats)
@@ -242,7 +294,7 @@ class DeltaWire:
     tombstones: FrozenSet[str]
 
 
-def decode_delta_wire(payload: bytes) -> DeltaWire:
+def decode_delta_wire(payload: Union[bytes, memoryview]) -> DeltaWire:
     """Unpack a delta payload into :class:`DeltaWire` (no block application)."""
     obj = msgpack.unpackb(payload, raw=False)
     assert obj["kind"] == "delta", obj["kind"]
@@ -343,9 +395,9 @@ def _resolve_leaf_programs(
 
 
 def apply_delta_chains(
-    requests: Sequence[
-        Tuple[FlatTree, Sequence[Union[bytes, DeltaWire]], Optional[BlockedTree]]
-    ],
+    requests: Sequence[Tuple[FlatTree,
+                             Sequence[Union[bytes, memoryview, DeltaWire]],
+                             Optional[BlockedTree]]],
     *,
     stats: Optional[Dict[str, int]] = None,
 ) -> List[Tuple[FlatTree, BlockedTree]]:
@@ -383,7 +435,7 @@ def apply_delta_chains(
         units: List[_LeafProgram] = []
         for ri, (base, payloads, _) in enumerate(requests):
             wires = [
-                decode_delta_wire(p) if isinstance(p, bytes) else p
+                p if isinstance(p, DeltaWire) else decode_delta_wire(p)
                 for p in payloads
             ]
             wire_chains.append(wires)
@@ -489,7 +541,7 @@ def apply_delta_chains(
 
 def apply_delta_chain(
     base: FlatTree,
-    payloads: Sequence[Union[bytes, DeltaWire]],
+    payloads: Sequence[Union[bytes, memoryview, DeltaWire]],
     *,
     base_blocked: Optional[BlockedTree] = None,
 ) -> FlatTree:

@@ -93,7 +93,7 @@ class Codec:
             ctx.workers = workers
         return ctx.mt
 
-    def compress(self, payload: bytes) -> bytes:
+    def compress(self, payload: bytes | memoryview) -> bytes:
         with _span("objects.compress") as sp:
             zstd = self.backend == "zstd"
             workers = self._workers(len(payload)) if zstd else 0
@@ -116,7 +116,7 @@ class Codec:
                 return self._zstd().d.decompress(blob)
             return zlib.decompress(blob)
 
-    def compressed_size(self, payload: bytes) -> int:
+    def compressed_size(self, payload: bytes | memoryview) -> int:
         """Bytes this payload would occupy at rest (the measured Δ)."""
         return len(self.compress(payload))
 
@@ -136,7 +136,7 @@ class ObjectStore:
     def _path(self, key: str) -> Path:
         return self.root / "objects" / f"{key[:2]}" / f"{key[2:]}.zst"
 
-    def put(self, payload: bytes) -> tuple[str, int]:
+    def put(self, payload: bytes | memoryview) -> tuple[str, int]:
         """Store a blob; returns (key, stored_bytes)."""
         with _span("hash.sha256"):
             key = hashlib.sha256(payload).hexdigest()

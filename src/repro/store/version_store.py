@@ -127,7 +127,8 @@ class _PayloadProvider:
 
     def __init__(self, store: "VersionStore") -> None:
         self._store = store
-        self._memo: Dict[Tuple[int, int], Tuple[bytes, Dict]] = {}
+        self._memo: Dict[Tuple[int, int],
+                         Tuple[Union[bytes, memoryview], Dict]] = {}
 
     def flat(self, vid: int) -> FlatTree:
         return self._store._checkout_flat(vid)
@@ -135,7 +136,9 @@ class _PayloadProvider:
     def full_payload(self, vid: int) -> bytes:
         return self[(0, vid)][0]
 
-    def __getitem__(self, key: Tuple[int, int]) -> Tuple[bytes, Dict]:
+    def __getitem__(
+        self, key: Tuple[int, int]
+    ) -> Tuple[Union[bytes, memoryview], Dict]:
         if key not in self._memo:
             src, dst = key
             if src == 0:
@@ -593,7 +596,8 @@ class VersionStore:
     def _apply_solution(self, sol: StorageSolution, cache: _PayloadProvider) -> None:
         # phase 1: encode every chosen edge against the *old* storage graph
         # (the provider checkouts must not observe a half-rewritten graph)
-        encoded: Dict[int, Tuple[int, bytes, Optional[Dict]]] = {}
+        encoded: Dict[int, Tuple[int, Union[bytes, memoryview],
+                                 Optional[Dict]]] = {}
         for vid, parent in sol.parent.items():
             payload, stats = cache[(parent, vid)]
             encoded[vid] = (parent, payload, stats)

@@ -20,7 +20,7 @@ import ml_dtypes
 import msgpack
 import numpy as np
 
-from repro.store.delta import decode_delta_wire
+from repro.store.delta import DeltaWire, decode_delta_wire
 
 BLOCK = 4096  # bytes per storage block: (8, 128) int32
 
@@ -58,7 +58,7 @@ def apply_sparse(base: np.ndarray, idx: np.ndarray, blocks: np.ndarray,
 
 def apply_delta_ref(base: FlatTree, payload) -> FlatTree:
     """One delta hop: ``base`` turned into the version ``payload`` encodes."""
-    wire = decode_delta_wire(payload) if isinstance(payload, bytes) else payload
+    wire = payload if isinstance(payload, DeltaWire) else decode_delta_wire(payload)
     out: FlatTree = {}
     for key, arr in base.items():
         if key not in wire.tombstones:
